@@ -19,9 +19,9 @@ from repro.fl.strategies import get_strategy
 
 
 def build(wire):
-    from repro import compat
-
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh(
+        (1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     tag = hierarchical_fl(param_wire_dtype="f32", agg_wire_dtype=wire)
     plan = lower_tag_to_mesh(tag, ("data",))
     strat = get_strategy("fedavg")

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core.mesh_lowering import AggregationPlan
 from repro.fl.privacy import DPConfig, clip_and_noise
 from repro.fl.strategies import ServerStrategy
@@ -48,6 +47,42 @@ class FedStepConfig:
     participation: float = 1.0
 
 
+def local_round(
+    loss_fn: LossFn, params: Tree, batch: Tree, rng: jax.Array,
+    config: FedStepConfig,
+) -> Tuple[Tree, jax.Array]:
+    """One client's local round: ``config.local_steps`` SGD steps, one per
+    equal microbatch split of its batch. Returns (local params, last loss)."""
+
+    def one_step(carry, xs):
+        p, _ = carry
+        micro, step_rng = xs
+        loss, grads = jax.value_and_grad(loss_fn)(p, micro, step_rng)
+        new_p = jax.tree_util.tree_map(
+            lambda w, g: w - config.local_lr * g.astype(w.dtype), p, grads
+        )
+        return (new_p, loss), None
+
+    # split the client batch into local_steps microbatches along the
+    # batch dim (dim 0; positions lead with the 3 M-RoPE streams)
+    k = config.local_steps
+
+    def split(path, x):
+        if any(getattr(p, "key", None) == "positions" for p in path):
+            b = x.shape[1]
+            out = x.reshape((x.shape[0], k, b // k) + x.shape[2:])
+            return jnp.moveaxis(out, 1, 0)
+        b = x.shape[0]
+        return x.reshape((k, b // k) + x.shape[1:])
+
+    micro = jax.tree_util.tree_map_with_path(split, batch)
+    rngs = jax.random.split(rng, config.local_steps)
+    (final_params, last_loss), _ = jax.lax.scan(
+        one_step, (params, jnp.float32(0.0)), (micro, rngs)
+    )
+    return final_params, last_loss
+
+
 def make_fl_train_step(
     loss_fn: LossFn,
     strategy: ServerStrategy,
@@ -67,37 +102,6 @@ def make_fl_train_step(
     client_axes: Tuple[str, ...] = plan.all_axes
     auto_axes = frozenset(a for a in mesh.axis_names if a not in client_axes)
 
-    def local_round(params: Tree, batch: Tree, rng: jax.Array) -> Tuple[Tree, jax.Array]:
-        """Runs on one client: local_steps of SGD on microbatch splits."""
-
-        def one_step(carry, xs):
-            p, _ = carry
-            micro, step_rng = xs
-            loss, grads = jax.value_and_grad(loss_fn)(p, micro, step_rng)
-            new_p = jax.tree_util.tree_map(
-                lambda w, g: w - config.local_lr * g.astype(w.dtype), p, grads
-            )
-            return (new_p, loss), None
-
-        # split the client batch into local_steps microbatches along the
-        # batch dim (dim 0; positions lead with the 3 M-RoPE streams)
-        k = config.local_steps
-
-        def split(path, x):
-            if any(getattr(p, "key", None) == "positions" for p in path):
-                b = x.shape[1]
-                out = x.reshape((x.shape[0], k, b // k) + x.shape[2:])
-                return jnp.moveaxis(out, 1, 0)
-            b = x.shape[0]
-            return x.reshape((k, b // k) + x.shape[1:])
-
-        micro = jax.tree_util.tree_map_with_path(split, batch)
-        rngs = jax.random.split(rng, config.local_steps)
-        (final_params, last_loss), _ = jax.lax.scan(
-            one_step, (params, jnp.float32(0.0)), (micro, rngs)
-        )
-        return final_params, last_loss
-
     def step_body(params: Tree, server_state: Tree, batch: Tree, rng: jax.Array):
         # fold the client coordinates into the rng so clients differ
         idx = jnp.int32(0)
@@ -111,7 +115,7 @@ def make_fl_train_step(
                 lambda g: (-config.local_lr * g).astype(jnp.float32), grads
             )
         else:
-            local_params, loss = local_round(params, batch, rng)
+            local_params, loss = local_round(loss_fn, params, batch, rng, config)
             delta = jax.tree_util.tree_map(
                 lambda lp, p: (lp - p).astype(jnp.float32), local_params, params
             )
@@ -191,7 +195,7 @@ def make_fl_train_step(
         )
 
     def step(params: Tree, server_state: Tree, batch: Tree, rng: jax.Array):
-        shardmapped = compat.shard_map(
+        shardmapped = jax.shard_map(
             step_body,
             mesh=mesh,
             in_specs=(
@@ -205,7 +209,8 @@ def make_fl_train_step(
                 spec_tree(server_state, P()),
                 {"loss": P(), "delta_norm": P(), "participants": P()},
             ),
-            manual_axes=set(client_axes),
+            axis_names=set(client_axes),
+            check_vma=False,
         )
         return shardmapped(params, server_state, batch, rng)
 

@@ -14,7 +14,8 @@ Two modes:
   can be FMA-contracted. The result is bit-identical to the sequential
   per-client ``tree_map`` accumulation the roles used before the fused
   path existed (verified by ``tests/test_fused_agg.py``), at the cost of
-  one extra materialized (C, N) buffer.
+  one extra materialized (C, N) buffer. The final division runs on the
+  host in numpy, and the result is a numpy array.
 
 Dispatch: on an accelerator the Pallas kernels run natively; on CPU the
 wrappers route to plain jnp implementations with the same op structure
@@ -85,23 +86,15 @@ def stack_client_trees(trees):
     return jax.tree_util.tree_unflatten(treedef, stacked)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def _fused_flat(deltas, weights, den, *, block_n, interpret):
-    C, N = deltas.shape
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fused_flat(deltas, weights, den, *, interpret):
     if interpret is None:
         if _on_cpu():
             # same math as the kernel ((w @ d) / den), plain XLA dot
             w = weights.astype(jnp.float32)
             return (w @ deltas.astype(jnp.float32)) / den[0]
         interpret = False
-    block = min(block_n, N)
-    pad = (-N) % block
-    if pad:
-        deltas = jnp.pad(deltas, ((0, 0), (0, pad)))
-    out = weighted_aggregate(
-        deltas, weights, den, block_n=block, interpret=interpret
-    )
-    return out[:N] if pad else out
+    return weighted_aggregate(deltas, weights, den, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -112,25 +105,19 @@ def _scale_rows(deltas, weights):
     return deltas.astype(jnp.float32) * weights.astype(jnp.float32)[:, None]
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def _fold_flat(scaled, den, *, block_n, interpret):
-    C, N = scaled.shape
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _fold_flat(scaled, *, interpret):
     if interpret is None:
         if _on_cpu():
             # same op structure as the fold kernel (adds only, client
             # order), vectorized by XLA — bit-identical, no Pallas
             # interpreter overhead
             acc = scaled[0]
-            for c in range(1, C):
+            for c in range(1, scaled.shape[0]):
                 acc = acc + scaled[c]
-            return acc / den[0]
+            return acc
         interpret = False
-    block = min(block_n, N)
-    pad = (-N) % block
-    if pad:
-        scaled = jnp.pad(scaled, ((0, 0), (0, pad)))
-    out = fold_scaled(scaled, den, block_n=block, interpret=interpret)
-    return out[:N] if pad else out
+    return fold_scaled(scaled, interpret=interpret)
 
 
 def aggregate_flat(
@@ -138,10 +125,9 @@ def aggregate_flat(
     weights: jax.Array,  # (C,)
     *,
     denom=None,  # scalar normalizer; default sum(weights) (clamped > 0)
-    block_n: int = 65_536,
     interpret: bool = None,  # type: ignore[assignment]
     exact: bool = False,
-) -> jax.Array:
+) -> jax.Array | np.ndarray:
     deltas = jnp.asarray(deltas)
     weights = jnp.asarray(weights, jnp.float32)
     if denom is None:
@@ -149,11 +135,12 @@ def aggregate_flat(
     else:
         den = jnp.asarray(denom, jnp.float32).reshape(1)
     if not exact:
-        return _fused_flat(
-            deltas, weights, den, block_n=block_n, interpret=interpret
-        )
+        return _fused_flat(deltas, weights, den, interpret=interpret)
     scaled = _scale_rows(deltas, weights)
-    return _fold_flat(scaled, den, block_n=block_n, interpret=interpret)
+    summed = np.asarray(_fold_flat(scaled, interpret=interpret))
+    # divide on the host, as the sequential fold does: the TPU's f32
+    # division is not correctly rounded (up to 2 ulp on a v5e)
+    return summed / np.asarray(den)[0]
 
 
 def aggregate_tree(client_trees, weights, *, denom=None, interpret=None,

@@ -10,18 +10,15 @@ Mesh shapes (TPU v5e pods):
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
-
-
-def make_smoke_mesh():
-    """1-device mesh with the production axis names (CPU tests)."""
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 # Hardware constants for the roofline analysis (TPU v5e).

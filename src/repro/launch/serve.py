@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.cache import use_compile_cache
 from repro.models.api import build_model
 
 
@@ -29,6 +30,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     bundle = build_model(cfg)
     rng = jax.random.key(0)

@@ -237,6 +237,16 @@ def _worker_body(
                 pass
 
 
+def _pin_to_cpu() -> None:
+    """First statement of every spawned worker process: the driver process
+    owns the accelerator, and a chip belongs to one process at a time, so
+    workers run JAX on the CPU. Applied before the worker touches any JAX
+    backend; ``jax_platforms`` is read when the first backend starts."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _worker_entry(
     address: Any,
     job: JobSpec,
@@ -251,6 +261,7 @@ def _worker_entry(
     drop_ack: Any = None,
 ) -> None:
     """Entry point of a dedicated (one-worker) spawned process."""
+    _pin_to_cpu()
     _worker_body(
         address, job, worker, hyperparams, static_members, program_cls,
         barrier, result_q, barrier_timeout, policy, drop_ack,
@@ -283,6 +294,7 @@ def _pool_host_entry(
     are merged over ``job.hyperparams`` here — the big shared entries (e.g.
     ``init_weights``) cross the process boundary once per host, not once
     per worker."""
+    _pin_to_cpu()
     acks: Dict[str, threading.Event] = {}
     acks_lock = threading.Lock()
 

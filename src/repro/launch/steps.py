@@ -19,7 +19,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.mesh_lowering import lower_tag_to_mesh
 from repro.core.tag import TAG
 from repro.core.topologies import classical_fl, hierarchical_fl
-from repro.fl.fedstep import FedStepConfig, init_server_state, make_fl_train_step
+from repro.fl.fedstep import (
+    FedStepConfig,
+    init_server_state,
+    local_round,
+    make_fl_train_step,
+)
 from repro.fl.strategies import get_strategy
 from repro.launch import sharding as shd
 from repro.models.api import ModelBundle, build_model
@@ -142,34 +147,15 @@ def build_train_step(
             state_shapes,
         )
         in_sh = (p_shard, s_shard, None, rng_shard)  # batch filled by caller
-        out_sh = (p_shard, s_shard, {"loss": rep, "delta_norm": rep})
+        out_sh = (p_shard, s_shard,
+                  {"loss": rep, "delta_norm": rep, "participants": rep})
         return bundle, TrainSetup(step, init_state, client_axes, tag, in_sh, out_sh)
 
     # ---- degenerate single client: plain data-parallel local SGD --------
-    # (microbatched over local_steps like the FL local round, so activation
-    # memory is bounded the same way)
+    # (the FL local round over the whole batch, so activation memory is
+    # bounded the same way)
     def step(params, state, batch, rng):
-        k = fed.local_steps
-
-        def split(x):
-            b = x.shape[0]
-            return x.reshape((k, b // k) + x.shape[1:])
-
-        micro = jax.tree_util.tree_map(split, batch)
-        rngs = jax.random.split(rng, k)
-
-        def one(carry, xs):
-            p, _ = carry
-            mb, r = xs
-            loss, grads = jax.value_and_grad(loss_fn)(p, mb, r)
-            p = jax.tree_util.tree_map(
-                lambda w, g: w - fed.local_lr * g.astype(w.dtype), p, grads
-            )
-            return (p, loss), None
-
-        (new_params, loss), _ = jax.lax.scan(
-            one, (params, jnp.float32(0.0)), (micro, rngs)
-        )
+        new_params, loss = local_round(loss_fn, params, batch, rng, fed)
         dnorm = jnp.sqrt(
             sum(jnp.sum(jnp.square((a - b).astype(jnp.float32)))
                 for a, b in zip(jax.tree_util.tree_leaves(new_params),

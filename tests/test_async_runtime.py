@@ -257,7 +257,6 @@ class TestDeadlineSelector:
 class TestFedStepParticipation:
     def test_partial_participation_renormalizes(self):
         import jax
-        from repro import compat
         from repro.core.mesh_lowering import lower_tag_to_mesh
         from repro.fl.fedstep import (
             FedStepConfig,
@@ -265,7 +264,7 @@ class TestFedStepParticipation:
             make_fl_train_step,
         )
 
-        mesh = compat.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
         plan = lower_tag_to_mesh(classical_fl(), ("data",))
         strat = get_strategy("fedavg")
 

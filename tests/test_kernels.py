@@ -105,6 +105,21 @@ class TestAggKernel:
         )
         assert out.tobytes() == seed.tobytes()
 
+    def test_exact_fold_kernel_pads_to_whole_blocks(self):
+        """At 64 clients the fold's block is 16,384 wide, so a parameter
+        axis one block and 5 elements long runs two grid steps over a
+        zero-padded tail; the padding never reaches the result."""
+        from repro.kernels.agg.kernel import block_n_for, fold_scaled
+
+        C = 64
+        N = block_n_for(C) + 5
+        d = np.random.default_rng(1).normal(size=(C, N)).astype(np.float32)
+        acc = d[0]
+        for c in range(1, C):
+            acc = acc + d[c]
+        out = np.asarray(fold_scaled(jnp.asarray(d), interpret=True))
+        assert out.tobytes() == acc.tobytes()
+
     @settings(max_examples=15, deadline=None)
     @given(
         C=st.integers(1, 8),
@@ -121,6 +136,19 @@ class TestAggKernel:
         # the mean lies within the per-element min/max envelope
         assert (out <= np.max(np.asarray(d), 0) + 1e-5).all()
         assert (out >= np.min(np.asarray(d), 0) - 1e-5).all()
+
+    @pytest.mark.parametrize("C", [1, 3, 8, 9, 64, 200])
+    def test_block_width_fits_scoped_vmem(self, C):
+        """The fold's block shrinks with the client count so that the
+        double-buffered f32 (C, block) input block stays within budget."""
+        from repro.kernels.agg.kernel import _IN_BLOCK_BUDGET, block_n_for
+
+        block = block_n_for(C)
+        rows = -(-C // 8) * 8
+        assert block % 128 == 0 and block <= 65_536
+        assert 2 * rows * block * 4 <= _IN_BLOCK_BUDGET
+        # and it is the widest power of two that does
+        assert block == 65_536 or 2 * rows * 2 * block * 4 > _IN_BLOCK_BUDGET
 
     def test_tree_roundtrip_shapes(self):
         tree = {"a": jnp.ones((4, 3, 5)), "b": jnp.zeros((4, 7))}

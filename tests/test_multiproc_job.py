@@ -28,6 +28,16 @@ W0 = {
 }
 
 
+class PlatformProbeTrainer(SeededSGDTrainer):
+    """Records the JAX platforms its worker process may start."""
+
+    def train(self):
+        import jax
+
+        self.metrics.append({"jax_platforms": jax.config.jax_platforms})
+        super().train()
+
+
 def _classical_job(rounds=3, n_datasets=3):
     tag = classical_fl(
         trainer_program="repro.transport.conformance.SeededSGDTrainer"
@@ -184,3 +194,24 @@ class TestFailureHandling:
         assert "global-aggregator-0" in res.errors
         # no zombie children: the driver reaped the whole tree
         assert_children_reaped()
+
+
+class TestWorkersLeaveTheDeviceToTheDriver:
+    @pytest.mark.parametrize("pool_size", [None, 1])
+    def test_spawned_workers_are_pinned_to_cpu(self, monkeypatch, pool_size):
+        """The driver process owns the accelerator; every spawned worker
+        process (dedicated or pool host) starts pinned to the CPU, whatever
+        environment it inherits."""
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        res = run_job_multiproc(
+            _classical_job(rounds=1, n_datasets=2), timeout=120,
+            program_overrides={"trainer": PlatformProbeTrainer},
+            pool_size=pool_size,
+        )
+        assert not res.errors, res.errors
+        seen = [
+            m["jax_platforms"]
+            for wid, prog in res.programs.items() if wid.startswith("trainer")
+            for m in prog.metrics if "jax_platforms" in m
+        ]
+        assert seen and set(seen) == {"cpu"}

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro import compat
 from repro.core.mesh_lowering import (
     AggregationStage,
     lower_tag_to_mesh,
@@ -18,7 +17,7 @@ from repro.fl.strategies import get_strategy
 
 
 def _mesh1():
-    return compat.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 
 
 PARAMS = {"w": jnp.array([1.0, 2.0]), "b": jnp.zeros((2, 2))}
@@ -112,10 +111,11 @@ class TestMeshLowering:
         def f(t):
             return stage_reduce_mean(t, stage)
 
-        out = compat.shard_map(
+        out = jax.shard_map(
             f, mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(),),
             out_specs=jax.sharding.PartitionSpec(),
+            check_vma=False,
         )(x)
         tol = 0.05 if wire == "int8" else 1e-2
         np.testing.assert_allclose(out["w"], x["w"], atol=tol)
