@@ -49,6 +49,7 @@ from typing import (
 
 import numpy as np
 
+from repro.core.spans import RECV, SEND, role_span, span
 from repro.core.tag import Channel as ChannelSpec
 
 _WIRE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "int8": 1}
@@ -337,6 +338,9 @@ class ChannelEnd:
         self.peer_role = peer_role
         self.peer_selector = peer_selector
         self._joined = False
+        role = me.rsplit("-", 1)[0]
+        self._recv_span = role_span(role, RECV)
+        self._send_span = role_span(role, SEND)
 
     # ----------------------------- lifecycle -------------------------- #
     def join(self) -> None:
@@ -349,10 +353,12 @@ class ChannelEnd:
 
     # ----------------------------- messaging -------------------------- #
     def send(self, end: str, msg: Any) -> None:
-        self._backend.send(self.channel, self.group, self.me, end, msg)
+        with span(self._send_span):
+            self._backend.send(self.channel, self.group, self.me, end, msg)
 
     def recv(self, end: str, timeout: Optional[float] = 30.0) -> Any:
-        return self._backend.recv(self.channel, self.group, self.me, end, timeout)
+        with span(self._recv_span):
+            return self._backend.recv(self.channel, self.group, self.me, end, timeout)
 
     def recv_fifo(self, ends: Sequence[str], timeout: Optional[float] = 30.0):
         """Yield (end, message) for each end, in arrival (FIFO) order."""
@@ -389,7 +395,10 @@ class ChannelEnd:
         if not ends:
             return
         if _FANOUT_ENABLED and len(ends) > 1:
-            self._backend.send_many(self.channel, self.group, self.me, list(ends), msg)
+            with span(self._send_span):
+                self._backend.send_many(
+                    self.channel, self.group, self.me, list(ends), msg
+                )
         else:
             for end in ends:
                 self.send(end, msg)
@@ -442,7 +451,11 @@ class ChannelEnd:
             for end in order
         ]
         for end, fut in zip(order, futs):
-            yield end, fut.result()
+            # the span closes before the yield: the consumer's fold is not
+            # the wait for this frame
+            with span(self._recv_span):
+                msg = fut.result()
+            yield end, msg
 
     # ----------------------------- topology --------------------------- #
     def ends(self) -> List[str]:

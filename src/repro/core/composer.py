@@ -13,6 +13,8 @@ from __future__ import annotations
 import threading
 from typing import Callable, Dict, List, Optional
 
+from repro.core.spans import role_span, span
+
 _current_composer = threading.local()
 
 
@@ -21,12 +23,15 @@ class ComposerError(RuntimeError):
 
 
 class Tasklet:
-    """A named execution unit. ``alias`` eases later chain modification."""
+    """A named execution unit. ``alias`` eases later chain modification.
+    ``span`` names the host span each run opens (``Composer.name_spans``);
+    a tasklet outside a role's chain opens none."""
 
     def __init__(self, alias: str, fn: Callable[[], object]) -> None:
         self.alias = alias
         self.fn = fn
         self.composer: Optional["Composer"] = None
+        self.span: Optional[str] = None
         comp = getattr(_current_composer, "value", None)
         if comp is not None:
             comp._register(self)
@@ -38,7 +43,10 @@ class Tasklet:
         return Chain([self]) >> other
 
     def run(self) -> object:
-        return self.fn()
+        if self.span is None:
+            return self.fn()
+        with span(self.span):
+            return self.fn()
 
     # ------------------------------------------------------------------ #
     # Table 1 surgical-edit API
@@ -163,6 +171,16 @@ class Chain:
         chain, idx = found
         del chain.nodes[idx]
 
+    def tasklets(self) -> List[Tasklet]:
+        """Every tasklet of the chain, loop bodies included, in order."""
+        out: List[Tasklet] = []
+        for node in self.nodes:
+            if isinstance(node, Tasklet):
+                out.append(node)
+            elif isinstance(node, LoopNode):
+                out.extend(node.body.tasklets())
+        return out
+
     def aliases(self) -> List[str]:
         out: List[str] = []
         for node in self.nodes:
@@ -214,6 +232,12 @@ class Composer:
         if t is None or self.chain is None:
             return False
         return self.chain._locate(t) is not None
+
+    def name_spans(self, role: str) -> None:
+        """Name every tasklet's span of the final chain ``<role>/<alias>``."""
+        if self.chain is not None:
+            for t in self.chain.tasklets():
+                t.span = role_span(role, t.alias)
 
     def run(self) -> None:
         if self.chain is None:

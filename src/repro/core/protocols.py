@@ -235,11 +235,13 @@ class WeightSync(RoundProtocol):
         role.peak_buffered = max(role.peak_buffered, acc.peak_buffered)
         # observability (job-result metrics): how many updates were folded,
         # over how many frames the server actually received, at what peak
-        # buffering — the previously test-only attributes, surfaced
+        # buffering, and the bytes the fold moved to and from the device
         role.metrics.append({
             "agg_folds": acc.count,
             "agg_frames": len(blocks) if blocks else acc.count,
             "peak_buffered": role.peak_buffered,
+            "h2d_bytes": acc.h2d_bytes,
+            "d2h_bytes": acc.d2h_bytes,
         })
         mean, total = acc.finalize()
         if mean is not None:

@@ -20,6 +20,7 @@ import numpy as np
 from repro.core.channels import ChannelEnd, ChannelManager
 from repro.core.composer import CloneComposer, Composer, Loop, Tasklet
 from repro.core.expansion import WorkerConfig
+from repro.core.spans import FOLD_ADD, FOLD_FINALIZE, FOLD_PARTIAL, FOLD_SCALE, span
 from repro.core.tag import TAG
 
 
@@ -208,6 +209,11 @@ class StreamingMean:
     through the separately-jitted pair from ``repro.fl.strategies`` (the
     same no-FMA split as the kernel's exact mode); ``None`` auto-dispatches
     like ``weighted_mean``.
+
+    ``h2d_bytes`` counts the numpy leaves handed to a jitted call, and
+    ``d2h_bytes`` the results turned back into numpy: on the fused path
+    ``2·N`` for the first update of ``N`` bytes and ``3·N`` for each later
+    one (update and accumulator in, sum out); 0 on the host path.
     """
 
     def __init__(self, fused: Optional[bool] = None) -> None:
@@ -216,6 +222,8 @@ class StreamingMean:
         self._total = 0.0
         self.count = 0
         self.peak_buffered = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
 
     def _resolve_fused(self, weights: Any) -> bool:
         import jax
@@ -242,22 +250,40 @@ class StreamingMean:
             from repro.fl.strategies import _add_scaled, _scale_delta
 
             w = np.float32(n)
-            scaled = jax.tree_util.tree_map(
-                lambda x: _scale_delta(np.asarray(x), w), weights
-            )
-            if self._acc is None:
-                self._acc = jax.tree_util.tree_map(np.asarray, scaled)
-            else:
-                self._acc = jax.tree_util.tree_map(
-                    lambda a, s: np.asarray(_add_scaled(a, s)),
-                    self._acc, scaled,
+            with span(FOLD_SCALE):
+                scaled = jax.tree_util.tree_map(
+                    lambda x: _scale_delta(self._to_device(x), w), weights
                 )
+            with span(FOLD_ADD):
+                if self._acc is None:
+                    self._acc = jax.tree_util.tree_map(self._to_host, scaled)
+                else:
+                    self._acc = jax.tree_util.tree_map(
+                        lambda a, s: self._to_host(
+                            _add_scaled(self._to_device(a), s)
+                        ),
+                        self._acc, scaled,
+                    )
             return
-        scaled = jax.tree_util.tree_map(lambda x: np.asarray(x) * n, weights)
-        if self._acc is None:
-            self._acc = scaled
-        else:
-            self._acc = jax.tree_util.tree_map(np.add, self._acc, scaled)
+        with span(FOLD_SCALE):
+            scaled = jax.tree_util.tree_map(lambda x: np.asarray(x) * n, weights)
+        with span(FOLD_ADD):
+            if self._acc is None:
+                self._acc = scaled
+            else:
+                self._acc = jax.tree_util.tree_map(np.add, self._acc, scaled)
+
+    def _to_device(self, x: Any) -> np.ndarray:
+        """``x`` as the numpy leaf a jitted call copies in, counted."""
+        x = np.asarray(x)
+        self.h2d_bytes += x.nbytes
+        return x
+
+    def _to_host(self, x: Any) -> np.ndarray:
+        """A jitted call's result pulled into numpy, counted."""
+        x = np.asarray(x)
+        self.d2h_bytes += x.nbytes
+        return x
 
     def partial(self) -> Tuple[Optional[Any], float]:
         """The raw running state: ``(weighted_sum_tree, total_weight)``.
@@ -282,17 +308,19 @@ class StreamingMean:
         self._total += float(total)
         self.count += int(count)
         self.peak_buffered = max(self.peak_buffered, 1)
-        if self._acc is None:
-            self._acc = jax.tree_util.tree_map(np.asarray, acc)
-        else:
-            self._acc = jax.tree_util.tree_map(np.add, self._acc, acc)
+        with span(FOLD_PARTIAL):
+            if self._acc is None:
+                self._acc = jax.tree_util.tree_map(np.asarray, acc)
+            else:
+                self._acc = jax.tree_util.tree_map(np.add, self._acc, acc)
 
     def finalize(self) -> Tuple[Optional[Any], float]:
         import jax
 
         if self._acc is None or self._total <= 0:
             return None, 0.0
-        mean = jax.tree_util.tree_map(lambda x: x / self._total, self._acc)
+        with span(FOLD_FINALIZE):
+            mean = jax.tree_util.tree_map(lambda x: x / self._total, self._acc)
         return mean, self._total
 
 
@@ -404,6 +432,7 @@ class Role(abc.ABC):
         # surgery) so the protocol sees the final chain; the default
         # weight-sync protocol leaves chains untouched
         self.protocol.rewrite_chain(self.composer)
+        self.composer.name_spans(self.ctx.worker.role)
         self.composer.run()
 
     def on_dropped(self, at: float) -> None:
