@@ -233,9 +233,11 @@ class WeightSync(RoundProtocol):
             for _, msg in end.recv_ordered(end.ends()):
                 acc.fold(msg["weights"], float(msg.get("num_samples", 1)))
         role.peak_buffered = max(role.peak_buffered, acc.peak_buffered)
+        mean, total = acc.finalize()
         # observability (job-result metrics): how many updates were folded,
         # over how many frames the server actually received, at what peak
         # buffering, and the bytes the fold moved to and from the device
+        # (finalize's pull of the accumulator included)
         role.metrics.append({
             "agg_folds": acc.count,
             "agg_frames": len(blocks) if blocks else acc.count,
@@ -243,7 +245,6 @@ class WeightSync(RoundProtocol):
             "h2d_bytes": acc.h2d_bytes,
             "d2h_bytes": acc.d2h_bytes,
         })
-        mean, total = acc.finalize()
         if mean is not None:
             role.agg_weights = mean
             role.agg_samples = int(total)

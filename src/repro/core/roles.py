@@ -191,6 +191,17 @@ def weighted_mean(
     return jax.tree_util.tree_map(lambda x: x / total, acc), total
 
 
+def _device_free_bytes() -> Optional[int]:
+    """Bytes free on the device jitted calls run on, or ``None`` where it
+    does not say (the CPU)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
 class StreamingMean:
     """O(1)-memory streaming counterpart of ``weighted_mean``.
 
@@ -210,15 +221,22 @@ class StreamingMean:
     same no-FMA split as the kernel's exact mode); ``None`` auto-dispatches
     like ``weighted_mean``.
 
+    On the fused path the accumulator stays on the device between folds and
+    is pulled to numpy once, when it is read (``finalize``, ``partial``,
+    ``fold_partial``), where the device has room for it beside a scaled
+    update: ``2·N`` plus two of its largest leaves free at the first fold,
+    for updates of ``N`` bytes. Without that room each sum goes back to the
+    host as it is made, and the device holds about ``N`` plus two leaves.
     ``h2d_bytes`` counts the numpy leaves handed to a jitted call, and
-    ``d2h_bytes`` the results turned back into numpy: on the fused path
-    ``2·N`` for the first update of ``N`` bytes and ``3·N`` for each later
-    one (update and accumulator in, sum out); 0 on the host path.
+    ``d2h_bytes`` the device leaves turned back into numpy: for a round of
+    ``C`` updates ``C·N`` in and ``N`` out with the accumulator kept on the
+    device, ``(2·C − 1)·N`` in and ``C·N`` out without; 0 on the host path.
     """
 
     def __init__(self, fused: Optional[bool] = None) -> None:
         self._fused = fused
         self._acc: Any = None
+        self._resident: Optional[bool] = None
         self._total = 0.0
         self.count = 0
         self.peak_buffered = 0
@@ -250,20 +268,22 @@ class StreamingMean:
             from repro.fl.strategies import _add_scaled, _scale_delta
 
             w = np.float32(n)
+            settle = (lambda x: x) if self._keeps_acc(weights) else self._to_host
             with span(FOLD_SCALE):
-                scaled = jax.tree_util.tree_map(
-                    lambda x: _scale_delta(self._to_device(x), w), weights
-                )
+                leaves, treedef = jax.tree_util.tree_flatten(weights)
+                scaled = [_scale_delta(self._to_device(x), w) for x in leaves]
             with span(FOLD_ADD):
                 if self._acc is None:
-                    self._acc = jax.tree_util.tree_map(self._to_host, scaled)
+                    acc = [settle(s) for s in scaled]
                 else:
-                    self._acc = jax.tree_util.tree_map(
-                        lambda a, s: self._to_host(
-                            _add_scaled(self._to_device(a), s)
-                        ),
-                        self._acc, scaled,
-                    )
+                    acc = treedef.flatten_up_to(self._acc)
+                    self._acc = None  # each old leaf is freed as its sum is made
+                    for i, s in enumerate(scaled):
+                        a = acc[i]
+                        if not isinstance(a, jax.Array):  # a host sum goes in
+                            a = self._to_device(a)
+                        acc[i] = settle(_add_scaled(a, s))
+                self._acc = treedef.unflatten(acc)
             return
         with span(FOLD_SCALE):
             scaled = jax.tree_util.tree_map(lambda x: np.asarray(x) * n, weights)
@@ -272,6 +292,18 @@ class StreamingMean:
                 self._acc = scaled
             else:
                 self._acc = jax.tree_util.tree_map(np.add, self._acc, scaled)
+
+    def _keeps_acc(self, weights: Any) -> bool:
+        """Whether the fused accumulator stays on the device: decided at the
+        first fold, from the room the device has for it and a scaled update
+        (``2·N`` plus two of the largest leaves)."""
+        import jax
+
+        if self._resident is None:
+            sizes = [np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(weights)]
+            free = _device_free_bytes()
+            self._resident = free is None or 2 * sum(sizes) + 2 * max(sizes, default=0) <= free
+        return self._resident
 
     def _to_device(self, x: Any) -> np.ndarray:
         """``x`` as the numpy leaf a jitted call copies in, counted."""
@@ -285,14 +317,24 @@ class StreamingMean:
         self.d2h_bytes += x.nbytes
         return x
 
+    def _host_acc(self) -> Any:
+        """The accumulator as numpy; device leaves are pulled once."""
+        import jax
+
+        self._acc = jax.tree_util.tree_map(
+            lambda x: self._to_host(x) if isinstance(x, jax.Array) else x, self._acc
+        )
+        return self._acc
+
     def partial(self) -> Tuple[Optional[Any], float]:
         """The raw running state: ``(weighted_sum_tree, total_weight)``.
 
         This is the reduce plane's shard partial — unfinalized on purpose,
         so a downstream fold over several partials can divide once by the
         grand total exactly like :meth:`finalize` does, keeping the
-        one-shard case bit-identical to the per-frame streaming fold."""
-        return self._acc, self._total
+        one-shard case bit-identical to the per-frame streaming fold. The
+        tree is numpy: the hub packs it for the wire."""
+        return self._host_acc(), self._total
 
     def fold_partial(self, acc: Any, total: float, count: int = 1) -> None:
         """Absorb another accumulator's raw ``(acc, total)`` partial.
@@ -312,7 +354,7 @@ class StreamingMean:
             if self._acc is None:
                 self._acc = jax.tree_util.tree_map(np.asarray, acc)
             else:
-                self._acc = jax.tree_util.tree_map(np.add, self._acc, acc)
+                self._acc = jax.tree_util.tree_map(np.add, self._host_acc(), acc)
 
     def finalize(self) -> Tuple[Optional[Any], float]:
         import jax
@@ -320,7 +362,8 @@ class StreamingMean:
         if self._acc is None or self._total <= 0:
             return None, 0.0
         with span(FOLD_FINALIZE):
-            mean = jax.tree_util.tree_map(lambda x: x / self._total, self._acc)
+            # f32 division on the v5e is not correctly rounded: divide on the host
+            mean = jax.tree_util.tree_map(lambda x: x / self._total, self._host_acc())
         return mean, self._total
 
 

@@ -6,15 +6,15 @@ once, where the chain, channel end or fold is made, never per call. The
 trace keeps a worker thread's line under no useful name, so a span taken on
 a role's thread carries the role in its name.
 
-======================  ==================================================
+======================  ==========================================================
 ``<role>/<alias>``      one tasklet of the role's chain (``Tasklet.run``)
 ``<role>/recv``         the wait for, and the take of, one frame
 ``<role>/send``         handing a payload to the backend
 ``fold/scale``          ``StreamingMean.fold``: the update, scaled
 ``fold/add``            ``StreamingMean.fold``: added to the accumulator
 ``fold/partial``        ``StreamingMean.fold_partial``: a hub partial added
-``fold/finalize``       ``StreamingMean.finalize``: the host division
-======================  ==================================================
+``fold/finalize``       ``StreamingMean.finalize``: the pull and the host division
+======================  ==========================================================
 """
 from __future__ import annotations
 

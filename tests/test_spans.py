@@ -133,10 +133,10 @@ def test_copy_counters_count_what_the_fold_moves(fused_aggregation):
     for m in rounds:
         assert m["agg_folds"] == TRAINERS
         if fused_aggregation:
-            # first update: in, out; each later one: update and sum in, sum out
-            assert m["h2d_bytes"] == (1 + 2 * (TRAINERS - 1)) * N
-            assert m["d2h_bytes"] == TRAINERS * N
-            assert m["h2d_bytes"] + m["d2h_bytes"] == (2 + 3 * (TRAINERS - 1)) * N
+            # each update in; the accumulator out once, at finalize
+            assert m["h2d_bytes"] == TRAINERS * N
+            assert m["d2h_bytes"] == N
+            assert m["h2d_bytes"] + m["d2h_bytes"] == (TRAINERS + 1) * N
         else:
             assert m["h2d_bytes"] == m["d2h_bytes"] == 0
 

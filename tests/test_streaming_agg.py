@@ -17,6 +17,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core import roles
 from repro.core.expansion import JobSpec
 from repro.core.roles import StreamingMean, weighted_mean
 from repro.core.runtime import run_job
@@ -87,6 +88,115 @@ class TestStreamingMeanMatchesBatched:
         assert acc.finalize() == (None, 0.0)
         acc.fold({"w": np.ones((2,), np.float32)}, 0.0)
         assert acc.finalize() == (None, 0.0)
+
+
+def _updates(n_clients, seed):
+    rng = np.random.default_rng(seed)
+    return [(_ragged_tree(rng), float(rng.integers(1, 9))) for _ in range(n_clients)]
+
+
+def _folded(updates, fused):
+    acc = StreamingMean(fused=fused)
+    for tree, n in updates:
+        acc.fold(tree, n)
+    return acc
+
+
+def _nbytes(tree):
+    return sum(np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(tree))
+
+
+class TestFusedAccumulatorStaysOnDevice:
+    """On the fused path the accumulator is a device array between folds and
+    crosses to the host once, when it is read; the bytes are the host
+    path's."""
+
+    @pytest.mark.parametrize("n_clients", [1, 2, 8])
+    def test_pulled_once_at_finalize(self, n_clients):
+        updates = _updates(n_clients, 31 + n_clients)
+        nbytes = _nbytes(updates[0][0])
+        fused = StreamingMean(fused=True)
+        for tree, n in updates:
+            fused.fold(tree, n)
+            leaves = jax.tree_util.tree_leaves(fused._acc)
+            assert all(isinstance(x, jax.Array) for x in leaves)
+            assert fused.d2h_bytes == 0
+        assert fused.h2d_bytes == n_clients * nbytes
+        mean, total = fused.finalize()
+        assert fused.d2h_bytes == nbytes
+        host_mean, host_total = _folded(updates, fused=False).finalize()
+        assert total == host_total
+        assert all(isinstance(x, np.ndarray) for x in jax.tree_util.tree_leaves(mean))
+        assert _leaves_bytes(mean) == _leaves_bytes(host_mean)
+
+    @pytest.mark.parametrize("n_clients", [1, 2, 8])
+    def test_partial_is_the_host_partial_in_numpy(self, n_clients):
+        updates = _updates(n_clients, 41 + n_clients)
+        fused = _folded(updates, fused=True)
+        acc, total = fused.partial()
+        host_acc, host_total = _folded(updates, fused=False).partial()
+        assert total == host_total
+        assert all(isinstance(x, np.ndarray) for x in jax.tree_util.tree_leaves(acc))
+        assert _leaves_bytes(acc) == _leaves_bytes(host_acc)
+        assert fused.d2h_bytes == _nbytes(acc)
+
+    @pytest.mark.parametrize("n_clients", [1, 2, 8])
+    def test_fold_then_fold_partial_gives_the_host_bytes(self, n_clients):
+        updates = _updates(n_clients, 51 + n_clients)
+        part, part_total = _folded(_updates(3, 7), fused=False).partial()
+        fused = _folded(updates, fused=True)
+        host = _folded(updates, fused=False)
+        for acc in (fused, host):
+            acc.fold_partial(part, part_total, count=3)
+        assert fused.count == host.count == n_clients + 3
+        (mean, total), (host_mean, host_total) = fused.finalize(), host.finalize()
+        assert total == host_total
+        assert _leaves_bytes(mean) == _leaves_bytes(host_mean)
+        assert fused.d2h_bytes == _nbytes(part)
+
+    def test_fold_after_fold_partial_copies_the_host_sum_in(self):
+        updates = _updates(4, 61)
+        part, part_total = _folded(_updates(2, 8), fused=False).partial()
+        fused, host = StreamingMean(fused=True), StreamingMean(fused=False)
+        for acc in (fused, host):
+            acc.fold_partial(part, part_total, count=2)
+            for tree, n in updates:
+                acc.fold(tree, n)
+        nbytes = _nbytes(part)
+        assert fused.h2d_bytes == (len(updates) + 1) * nbytes
+        (mean, _), (host_mean, _) = fused.finalize(), host.finalize()
+        assert fused.d2h_bytes == nbytes
+        assert _leaves_bytes(mean) == _leaves_bytes(host_mean)
+
+    @pytest.mark.parametrize("n_clients", [1, 2, 8])
+    def test_without_room_on_the_device_each_sum_goes_back(self, n_clients, monkeypatch):
+        monkeypatch.setattr(roles, "_device_free_bytes", lambda: 0)
+        updates = _updates(n_clients, 71 + n_clients)
+        nbytes = _nbytes(updates[0][0])
+        fused = StreamingMean(fused=True)
+        for tree, n in updates:
+            fused.fold(tree, n)
+            leaves = jax.tree_util.tree_leaves(fused._acc)
+            assert not any(isinstance(x, jax.Array) for x in leaves)
+        assert fused.h2d_bytes == (2 * n_clients - 1) * nbytes
+        assert fused.d2h_bytes == n_clients * nbytes
+        mean, total = fused.finalize()
+        assert fused.d2h_bytes == n_clients * nbytes
+        host_mean, host_total = _folded(updates, fused=False).finalize()
+        assert total == host_total
+        assert _leaves_bytes(mean) == _leaves_bytes(host_mean)
+
+    @pytest.mark.parametrize("spare, resident", [(0, True), (-1, False), (None, True)])
+    def test_the_accumulator_stays_where_the_device_has_room(self, spare, resident,
+                                                             monkeypatch):
+        # room for the accumulator, a scaled update and two of its largest leaves
+        updates = _updates(2, 81)
+        sizes = [x.nbytes for x in jax.tree_util.tree_leaves(updates[0][0])]
+        free = None if spare is None else 2 * sum(sizes) + 2 * max(sizes) + spare
+        monkeypatch.setattr(roles, "_device_free_bytes", lambda: free)
+        fused = _folded(updates, fused=True)
+        leaves = jax.tree_util.tree_leaves(fused._acc)
+        assert all(isinstance(x, jax.Array) == resident for x in leaves)
 
 
 class TestStrategyStreamMatchesBatch:
