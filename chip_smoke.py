@@ -224,7 +224,7 @@ def phase_mesh4(cfg, batch_per_client: int = BATCH, seq: int = SEQ) -> dict:
     # params0 and one local round at a time
     @functools.partial(jax.jit, donate_argnums=3)
     def add_round(p, b, r, acc):
-        local, loss = local_round(bundle.loss_fn, p, b, r, fed)
+        local, loss, _ = local_round(bundle.loss_fn, p, b, r, fed)
         return jax.tree_util.tree_map(
             lambda a, lp, p0: a + (lp - p0).astype(jnp.float32), acc, local, p
         ), loss

@@ -27,7 +27,7 @@ def build(wire):
     strat = get_strategy("fedavg")
 
     def loss_fn(p, batch, rng):
-        return jnp.mean((batch["x"] @ p["w"] - batch["y"]) ** 2)
+        return jnp.mean((batch["x"] @ p["w"] - batch["y"]) ** 2), {}
 
     step = make_fl_train_step(loss_fn, strat, plan, mesh,
                               FedStepConfig(local_steps=2, local_lr=0.05))

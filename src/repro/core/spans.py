@@ -15,6 +15,21 @@ a role's thread carries the role in its name.
 ``fold/partial``        ``StreamingMean.fold_partial``: a hub partial added
 ``fold/finalize``       ``StreamingMean.finalize``: the pull and the host division
 ======================  ==========================================================
+
+Inside a jitted step the device's work is named by scopes instead: a
+``jax.named_scope`` puts its name into the op name of every operation
+traced under it, which the compiled program keeps as metadata. The model
+opens these once per layer:
+
+======================  ==========================================================
+``attn/proj``           q/k/v projections, the q/k norm, RoPE, the output projection
+``attn/core``           scores, causal mask, softmax and the weighted values
+``moe/route``           router logits, softmax, top-k and the load-balancing loss
+``moe/dispatch``        picks sorted by held expert, their tokens gathered
+``moe/experts``         the held experts' grouped matrix products
+``moe/combine``         expert outputs weighted and added back to their tokens
+``lm/ce``               the output head and the cross-entropy
+======================  ==========================================================
 """
 from __future__ import annotations
 
@@ -27,6 +42,16 @@ FOLD_ADD = "fold/add"
 FOLD_PARTIAL = "fold/partial"
 FOLD_FINALIZE = "fold/finalize"
 
+ATTN_PROJ = "attn/proj"
+ATTN_CORE = "attn/core"
+MOE_ROUTE = "moe/route"
+MOE_DISPATCH = "moe/dispatch"
+MOE_EXPERTS = "moe/experts"
+MOE_COMBINE = "moe/combine"
+LM_CE = "lm/ce"
+SCOPES = (ATTN_PROJ, ATTN_CORE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS,
+          MOE_COMBINE, LM_CE)
+
 
 def role_span(role: str, what: str) -> str:
     """``<role>/<what>``: the name of a span on ``role``'s thread."""
@@ -36,3 +61,9 @@ def role_span(role: str, what: str) -> str:
 def span(name: str) -> jax.profiler.TraceAnnotation:
     """The host span ``name``, entered with ``with span(name): ...``."""
     return jax.profiler.TraceAnnotation(name)
+
+
+def scope(name: str):
+    """The device scope ``name``, entered with ``with scope(name): ...``
+    while a jitted function traces."""
+    return jax.named_scope(name)

@@ -15,6 +15,10 @@ Semantics per round (classic FedAvg-style local SGD):
      cross-pod psum in the channel's wire dtype);
   4. the per-stage server strategy (FedAvg/FedAdam/...) produces the new
      global params, identical on every device.
+
+The loss function returns ``(loss, counters)``: counters the model keeps
+(a MoE layer's picks, ``repro.models.moe.COUNTERS``) are summed over the
+local steps and the clients and join the step's metrics.
 """
 from __future__ import annotations
 
@@ -30,7 +34,8 @@ from repro.fl.privacy import DPConfig, clip_and_noise
 from repro.fl.strategies import ServerStrategy
 
 Tree = Any
-LossFn = Callable[[Tree, Dict[str, jax.Array], jax.Array], jax.Array]
+LossFn = Callable[[Tree, Dict[str, jax.Array], jax.Array],
+                  Tuple[jax.Array, Dict[str, jax.Array]]]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,18 +55,20 @@ class FedStepConfig:
 def local_round(
     loss_fn: LossFn, params: Tree, batch: Tree, rng: jax.Array,
     config: FedStepConfig,
-) -> Tuple[Tree, jax.Array]:
+) -> Tuple[Tree, jax.Array, Dict[str, jax.Array]]:
     """One client's local round: ``config.local_steps`` SGD steps, one per
-    equal microbatch split of its batch. Returns (local params, last loss)."""
+    equal microbatch split of its batch. Returns (local params, last loss,
+    the loss function's counters summed over the steps)."""
 
     def one_step(carry, xs):
         p, _ = carry
         micro, step_rng = xs
-        loss, grads = jax.value_and_grad(loss_fn)(p, micro, step_rng)
+        (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            p, micro, step_rng)
         new_p = jax.tree_util.tree_map(
             lambda w, g: w - config.local_lr * g.astype(w.dtype), p, grads
         )
-        return (new_p, loss), None
+        return (new_p, loss), counters
 
     # split the client batch into local_steps microbatches along the
     # batch dim (dim 0; positions lead with the 3 M-RoPE streams)
@@ -77,10 +84,11 @@ def local_round(
 
     micro = jax.tree_util.tree_map_with_path(split, batch)
     rngs = jax.random.split(rng, config.local_steps)
-    (final_params, last_loss), _ = jax.lax.scan(
+    (final_params, last_loss), counters = jax.lax.scan(
         one_step, (params, jnp.float32(0.0)), (micro, rngs)
     )
-    return final_params, last_loss
+    return final_params, last_loss, jax.tree_util.tree_map(
+        lambda c: jnp.sum(c, axis=0), counters)
 
 
 def make_fl_train_step(
@@ -110,12 +118,14 @@ def make_fl_train_step(
         rng = jax.random.fold_in(rng, idx)
 
         if config.exchange == "grad":
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch, rng)
+            (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, batch, rng)
             delta = jax.tree_util.tree_map(
                 lambda g: (-config.local_lr * g).astype(jnp.float32), grads
             )
         else:
-            local_params, loss = local_round(loss_fn, params, batch, rng, config)
+            local_params, loss, counters = local_round(
+                loss_fn, params, batch, rng, config)
             delta = jax.tree_util.tree_map(
                 lambda lp, p: (lp - p).astype(jnp.float32), local_params, params
             )
@@ -175,6 +185,7 @@ def make_fl_train_step(
                 )
             ),
             "participants": n_part,
+            **jax.lax.psum(counters, client_axes),
         }
         return new_params, {"stages": new_stage_states}, metrics
 
@@ -207,7 +218,7 @@ def make_fl_train_step(
             out_specs=(
                 spec_tree(params, P()),
                 spec_tree(server_state, P()),
-                {"loss": P(), "delta_norm": P(), "participants": P()},
+                P(),  # every metric, replicated
             ),
             axis_names=set(client_axes),
             check_vma=False,

@@ -29,14 +29,14 @@ from repro.fl.strategies import get_strategy
 from repro.launch import sharding as shd
 from repro.models.api import ModelBundle, build_model
 from repro.models.config import ModelConfig
-from repro.models.moe import shard_profile
+from repro.models.shard_ctx import shard_profile
 
 Tree = Any
 
 
-def _with_moe_profile(fn, cfg: ModelConfig, mesh: Mesh,
-                      manual_axes: Tuple[str, ...] = ()):
-    """Activate the expert-parallel sharding profile while ``fn`` traces.
+def _with_profile(fn, cfg: ModelConfig, mesh: Mesh,
+                  manual_axes: Tuple[str, ...] = ()):
+    """Activate the activation-layout sharding profile while ``fn`` traces.
 
     The profile's batch axes are the *auto* axes only — constraints inside a
     partial-manual shard_map must not reference manual (client) axes.
@@ -57,18 +57,10 @@ def _with_moe_profile(fn, cfg: ModelConfig, mesh: Mesh,
         act = (auto_batch or None, None)
         stash = act
 
-    def size(axes):
-        n = 1
-        for a in axes or ():
-            n *= mesh.shape[a]
-        return n
-
-    min_blocks = size(auto_batch)
     axis_sizes = {a: mesh.shape[a] for a in mesh.axis_names}
 
     def wrapped(*a, **k):
-        with shard_profile(auto_batch, "model", min_blocks=min_blocks,
-                           act=act, stash=stash, axis_sizes=axis_sizes):
+        with shard_profile(act=act, stash=stash, axis_sizes=axis_sizes):
             return fn(*a, **k)
 
     return wrapped
@@ -134,7 +126,7 @@ def build_train_step(
         ) or client_axes
         plan = lower_tag_to_mesh(tag, ordered)
         step = make_fl_train_step(loss_fn, strategy, plan, mesh, fed)
-        step = _with_moe_profile(step, cfg, mesh, manual_axes=client_axes)
+        step = _with_profile(step, cfg, mesh, manual_axes=client_axes)
 
         def init_state(params):
             return init_server_state(strategy, plan, params)
@@ -147,28 +139,27 @@ def build_train_step(
             state_shapes,
         )
         in_sh = (p_shard, s_shard, None, rng_shard)  # batch filled by caller
-        out_sh = (p_shard, s_shard,
-                  {"loss": rep, "delta_norm": rep, "participants": rep})
+        out_sh = (p_shard, s_shard, rep)  # every metric, replicated
         return bundle, TrainSetup(step, init_state, client_axes, tag, in_sh, out_sh)
 
     # ---- degenerate single client: plain data-parallel local SGD --------
     # (the FL local round over the whole batch, so activation memory is
     # bounded the same way)
     def step(params, state, batch, rng):
-        new_params, loss = local_round(loss_fn, params, batch, rng, fed)
+        new_params, loss, counters = local_round(loss_fn, params, batch, rng, fed)
         dnorm = jnp.sqrt(
             sum(jnp.sum(jnp.square((a - b).astype(jnp.float32)))
                 for a, b in zip(jax.tree_util.tree_leaves(new_params),
                                 jax.tree_util.tree_leaves(params)))
         )
-        return new_params, state, {"loss": loss, "delta_norm": dnorm}
+        return new_params, state, {"loss": loss, "delta_norm": dnorm, **counters}
 
     def init_state(params):
         return ()
 
-    step = _with_moe_profile(step, cfg, mesh)
+    step = _with_profile(step, cfg, mesh)
     in_sh = (p_shard, (), None, rng_shard)
-    out_sh = (p_shard, (), {"loss": rep, "delta_norm": rep})
+    out_sh = (p_shard, (), rep)
     return bundle, TrainSetup(step, init_state, (), None, in_sh, out_sh)
 
 
@@ -192,11 +183,11 @@ def build_serve_step(cfg: ModelConfig, mesh: Mesh, max_len: int,
     c_shard = shd.cache_shardings(cache_shapes, cfg, mesh)
     rep = NamedSharding(mesh, P())
 
-    serve = _with_moe_profile(
+    serve = _with_profile(
         lambda params, cache, batch_in: bundle.serve_step(params, cache, batch_in),
         cfg, mesh,
     )
-    prefill = _with_moe_profile(
+    prefill = _with_profile(
         lambda params, batch_in, cache: bundle.prefill(params, batch_in, cache),
         cfg, mesh,
     )

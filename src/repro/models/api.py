@@ -31,7 +31,10 @@ Tree = Any
 class ModelBundle:
     cfg: ModelConfig
     init: Callable[[jax.Array], Tree]
-    loss_fn: Callable[[Tree, Dict[str, jax.Array], jax.Array], jax.Array]
+    # (params, batch, rng) -> (loss, counters): a MoE model counts its
+    # picks (``repro.models.moe.COUNTERS``), summed over layers; others none
+    loss_fn: Callable[[Tree, Dict[str, jax.Array], jax.Array],
+                      Tuple[jax.Array, Dict[str, jax.Array]]]
     init_cache: Callable[[int, int], Tree]
     serve_step: Callable[[Tree, Tree, Dict[str, jax.Array]], Tuple[jax.Array, Tree]]
     prefill: Callable[[Tree, Dict[str, jax.Array], Tree], Tuple[jax.Array, Tree]]
@@ -136,7 +139,7 @@ def _build_encdec(cfg: ModelConfig) -> ModelBundle:
 
     def loss_fn(params, batch, rng):
         del rng
-        return encdec.lm_loss(params, cfg, batch["tokens"], batch["frames"])
+        return encdec.lm_loss(params, cfg, batch["tokens"], batch["frames"]), {}
 
     def init_cache(batch_size, max_len):
         return encdec.init_cache(cfg, batch_size, max_len)

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import dense_apply, dense_init
+from repro.models.layers import dense_apply, dense_init, rmsnorm_apply, rmsnorm_init
 
 Tree = Dict[str, jax.Array]
 
@@ -25,23 +25,32 @@ NEG_INF = -1e30
 
 def attn_init(rng, cfg: ModelConfig, dtype) -> Tree:
     kq, kk, kv, ko = jax.random.split(rng, 4)
-    return {
+    p = {
         "wq": dense_init(kq, cfg.d_model, cfg.q_dim, dtype, bias=cfg.qkv_bias),
         "wk": dense_init(kk, cfg.d_model, cfg.kv_dim, dtype, bias=cfg.qkv_bias),
         "wv": dense_init(kv, cfg.d_model, cfg.kv_dim, dtype, bias=cfg.qkv_bias),
         "wo": dense_init(ko, cfg.q_dim, cfg.d_model, dtype),
     }
+    if cfg.qk_norm:  # one scale over head_dim, shared by every head
+        p["q_norm"] = rmsnorm_init(cfg.head_dim, dtype)
+        p["k_norm"] = rmsnorm_init(cfg.head_dim, dtype)
+    return p
 
 
 def project_q(p: Tree, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     B, S, _ = x.shape
-    return dense_apply(p["wq"], x).reshape(B, S, cfg.num_heads, cfg.head_dim)
+    q = dense_apply(p["wq"], x).reshape(B, S, cfg.num_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        q = rmsnorm_apply(p["q_norm"], q, cfg.norm_eps)
+    return q
 
 
 def project_kv(p: Tree, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     B, S, _ = x.shape
     k = dense_apply(p["wk"], x).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
     v = dense_apply(p["wv"], x).reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        k = rmsnorm_apply(p["k_norm"], k, cfg.norm_eps)
     return k, v
 
 
@@ -117,6 +126,17 @@ def chunked_attention(
     chunk = min(q_chunk, Sq)
 
     if use_scan and Sq % chunk == 0 and Sq > chunk:
+        if B > 1:
+            # one scan per sequence: with a batch dimension in the chunk's
+            # f32 (B, Hkv, G, c, Skv) scores, the softmax a TPU v5e
+            # compiles for 2 x 8,192 tokens takes 15x the time of two
+            # sequences run one after the other
+            return jnp.concatenate([
+                chunked_attention(
+                    q[b:b + 1], k[b:b + 1], v[b:b + 1], causal=causal,
+                    window=window, q_chunk=q_chunk, q_offset=q_offset,
+                    use_scan=True)
+                for b in range(B)], axis=0)
         nc = Sq // chunk
         qs = jnp.moveaxis(
             q.reshape(B, nc, chunk, Hkv, G, Dh), 1, 0

@@ -8,7 +8,9 @@ Kinds
 ``mlstm``  matrix-memory LSTM block, expand-2 projection (xlstm)
 ``slstm``  scalar-memory LSTM block                      (xlstm, every Nth)
 
-``apply(p, x, positions, cache, mode, cfg)`` returns ``(y, new_cache, aux)``:
+``apply(p, x, positions, cache, mode, cfg)`` returns ``(y, new_cache, stats)``
+(``stats``: what a MoE layer reports, ``moe.zero_stats()``'s keys; empty
+for every other kind):
 
 * mode ``"full"``   — causal self-attention / chunked scan over the whole
   sequence (training forward and prefill). If ``cache`` is not None it is
@@ -28,6 +30,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import ATTN_CORE, ATTN_PROJ, scope
 from repro.models import attention as attn
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -44,7 +47,6 @@ from repro.models.moe import moe_apply, moe_init
 from repro.models.ssd import slstm_scan, ssd_chunked, ssd_decode_step
 
 Tree = Dict[str, jax.Array]
-ZERO = jnp.float32(0.0)
 
 
 # ===================================================================== #
@@ -81,19 +83,21 @@ def _attn_core_full(
     cfg: ModelConfig,
 ) -> Tuple[jax.Array, Optional[Tree]]:
     """Full-sequence causal attention; optionally fills the cache (prefill)."""
-    q = attn.project_q(p, h, cfg)
-    k, v = attn.project_kv(p, h, cfg)
-    q = _rotate(q, positions, cfg)
-    k = _rotate(k, positions, cfg)
-    if cfg.attn_impl == "flash":
-        from repro.kernels.flash_attention.ops import flash_attention
+    with scope(ATTN_PROJ):
+        q = attn.project_q(p, h, cfg)
+        k, v = attn.project_kv(p, h, cfg)
+        q = _rotate(q, positions, cfg)
+        k = _rotate(k, positions, cfg)
+    with scope(ATTN_CORE):
+        if cfg.attn_impl == "flash":
+            from repro.kernels.flash_attention.ops import flash_attention
 
-        out = flash_attention(q, k, v, causal=True, window=cfg.sliding_window)
-    else:
-        out = attn.chunked_attention(
-            q, k, v, causal=True, window=cfg.sliding_window,
-            q_chunk=cfg.q_chunk, use_scan=cfg.scan_attn_chunks,
-        )
+            out = flash_attention(q, k, v, causal=True, window=cfg.sliding_window)
+        else:
+            out = attn.chunked_attention(
+                q, k, v, causal=True, window=cfg.sliding_window,
+                q_chunk=cfg.q_chunk, use_scan=cfg.scan_attn_chunks,
+            )
     new_cache = None
     if cache is not None:
         S = h.shape[1]
@@ -111,7 +115,9 @@ def _attn_core_full(
                 "v": v[:, -W:],
                 "pos": tpos[0, -W:].astype(jnp.int32),
             }
-    return attn.attn_output(p, out, cfg), new_cache
+    with scope(ATTN_PROJ):
+        out = attn.attn_output(p, out, cfg)
+    return out, new_cache
 
 
 def _attn_core_decode(
@@ -294,9 +300,9 @@ def block_apply(
     cfg: ModelConfig,
     kind: str,
 ) -> Tuple[jax.Array, Optional[Tree], jax.Array]:
-    """Returns (y, new_cache, aux_loss)."""
+    """Returns (y, new_cache, stats)."""
     decode = mode == "decode"
-    aux = ZERO
+    stats: Dict[str, jax.Array] = {}
     new_cache: Optional[Tree] = dict(cache) if cache is not None else None
 
     if kind in ("dense", "moe"):
@@ -312,10 +318,10 @@ def block_apply(
         x = x + a
         h = rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
         if kind == "moe":
-            m, aux = moe_apply(p["moe"], h, cfg)
+            m, stats = moe_apply(p["moe"], h, cfg)
         else:
             m = mlp_apply(p["mlp"], h, cfg.activation)
-        return x + m, new_cache, aux
+        return x + m, new_cache, stats
 
     if kind == "hymba":
         h = rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
@@ -338,7 +344,7 @@ def block_apply(
         )
         x = x + fused
         h = rmsnorm_apply(p["ln2"], x, cfg.norm_eps)
-        return x + mlp_apply(p["mlp"], h, cfg.activation), new_cache, aux
+        return x + mlp_apply(p["mlp"], h, cfg.activation), new_cache, stats
 
     if kind == "mlstm":
         h = rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
@@ -348,7 +354,7 @@ def block_apply(
             s, sc = ssd_apply_full(p["ssd"], h, cache["ssd"] if cache else None, cfg)
         if new_cache is not None:
             new_cache["ssd"] = sc
-        return x + s, new_cache, aux
+        return x + s, new_cache, stats
 
     if kind == "slstm":
         h = rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
@@ -363,7 +369,7 @@ def block_apply(
             hs, carry = slstm_scan(i_g, f_g, z_g, o_g, initial=init)
             if new_cache is not None:
                 new_cache = {"c": carry[0], "n": carry[1], "m": carry[2]}
-        return x + dense_apply(p["out"], hs), new_cache, aux
+        return x + dense_apply(p["out"], hs), new_cache, stats
 
     raise ValueError(f"unknown block kind {kind!r}")
 

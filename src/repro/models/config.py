@@ -42,6 +42,7 @@ class ModelConfig:
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # t/h/w splits of head_dim/2
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
+    qk_norm: bool = False  # RMSNorm of each q and k head before RoPE (Qwen3)
 
     # --- MoE ---
     num_experts: int = 0
@@ -49,8 +50,12 @@ class ModelConfig:
     moe_d_ff: int = 0
     moe_every: int = 1  # 1 = every layer is MoE; 2 = alternate dense/MoE
     shared_expert: bool = False
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # expert parallelism: this chip holds ``experts_held`` of the
+    # ``num_experts`` (0 = all), the ``expert_shard``-th consecutive share;
+    # the router still scores every expert
+    experts_held: int = 0
+    expert_shard: int = 0
 
     # --- SSM / hybrid ---
     ssm_state: int = 0
@@ -90,19 +95,32 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.is_moe and not (
+            0 < self.held_experts <= self.num_experts
+            and 0 <= self.expert_shard < self.num_experts // self.held_experts
+            and self.num_experts % self.held_experts == 0
+        ):
+            raise ValueError(
+                f"{self.arch_id}: {self.experts_held} experts held in share "
+                f"{self.expert_shard} do not divide {self.num_experts} experts")
 
     # ------------------------------------------------------------------ #
     @property
     def padded_vocab(self) -> int:
         """Embedding-table rows, padded to a multiple of 256 (Megatron-style)
         so the vocab dim shards on any reasonable model axis. Logits are
-        sliced back to ``vocab_size`` at the serving API boundary; padded
-        columns simply participate in the softmax during training."""
+        sliced back to ``vocab_size`` at the serving API boundary, and the
+        training softmax masks the padded rows out."""
         return -(-self.vocab_size // 256) * 256
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
 
     @property
     def q_dim(self) -> int:
@@ -135,6 +153,8 @@ class ModelConfig:
         per_attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
         if self.qkv_bias:
             per_attn += self.q_dim + 2 * self.kv_dim
+        if self.qk_norm:
+            per_attn += 2 * self.head_dim
         def ffn_params(ff: int) -> int:
             return 3 * d * ff  # swiglu/geglu: gate, up, down
 
@@ -162,7 +182,7 @@ class ModelConfig:
             n_dense = n_layers - n_moe
             moe = n_moe * (
                 per_attn
-                + self.num_experts * 3 * d * self.moe_d_ff
+                + self.held_experts * 3 * d * self.moe_d_ff
                 + d * self.num_experts
                 + (3 * d * self.d_ff if self.shared_expert else 0)
                 + 2 * d
@@ -178,7 +198,7 @@ class ModelConfig:
             return self.param_count()
         full = self.param_count()
         n_moe = self.num_layers // self.moe_every
-        all_experts = n_moe * self.num_experts * 3 * self.d_model * self.moe_d_ff
+        all_experts = n_moe * self.held_experts * 3 * self.d_model * self.moe_d_ff
         active_experts = (
             n_moe * self.experts_per_token * 3 * self.d_model * self.moe_d_ff
         )

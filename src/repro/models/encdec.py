@@ -209,5 +209,6 @@ def lm_loss(
     h, _, _ = decode_hidden(params, cfg, tokens, memory)
     unemb = params["embed"] if cfg.tie_embeddings else params["unembed"]
     return chunked_ce(
-        h[:, :-1], unemb, tokens[:, 1:], use_scan=cfg.scan_attn_chunks
+        h[:, :-1], unemb, tokens[:, 1:], cfg.vocab_size,
+        use_scan=cfg.scan_attn_chunks,
     )

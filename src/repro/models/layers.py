@@ -46,7 +46,11 @@ def embed_init(rng, vocab: int, d: int, dtype) -> Tree:
 
 
 def embed_apply(p: Tree, tokens: jax.Array) -> jax.Array:
-    return jnp.take(p["emb"], tokens, axis=0)
+    # looked up from an f32 view: the values are the table's, and the
+    # backward adds every occurrence of a token into its row in f32 before
+    # rounding once (a bf16 scatter-add would round at every occurrence)
+    emb = p["emb"]
+    return jnp.take(emb.astype(jnp.float32), tokens, axis=0).astype(emb.dtype)
 
 
 def unembed_apply(p: Tree, x: jax.Array) -> jax.Array:

@@ -1,69 +1,64 @@
-"""Mixture-of-Experts layer: top-k routing with capacity, sort-based dispatch.
+"""Mixture-of-Experts layer: top-k routing over every expert, dropless
+compute for the experts this chip holds.
 
-TPU adaptation notes (DESIGN.md): instead of the N x E x C one-hot dispatch
-einsum (whose dispatch tensor is quadratic in experts x capacity and blows
-VMEM/HBM for 64k-token shards), tokens are *sorted by expert id* and routed
-with scatter/gather — O(N·k·d) data movement, MXU-dense expert matmuls of
-static shape (E, C, d). Expert weights lead with the expert dim so the
-``model`` mesh axis shards them (expert parallelism); XLA inserts the
-all-to-all at the scatter/gather boundary.
+The router scores all ``num_experts`` experts, keeps the top
+``experts_per_token`` of each token and renormalises their weights to sum
+to one. The layer holds the weights of ``held_experts`` of them (the
+``expert_shard``-th consecutive share, ``ModelConfig.experts_held``), so
+the expert weights lead with that count. Under expert parallelism every
+chip holds one share and computes its experts' part of the result; on one
+chip the layer runs without the exchange, and picks for absent experts add
+nothing.
 
-Router aux loss is the standard load-balancing loss (Shazeer/Switch):
-``E * sum_e f_e * P_e`` with f the routed-token fraction and P the mean
-router probability.
+The picks that land on held experts are sorted by expert and run as one
+grouped matrix product per weight (``jax.lax.ragged_dot``), which computes
+only the rows its groups fill. The sorted buffer has a row for every pick
+that can land here, N · min(k, held), so no pick is dropped at any
+imbalance. Each call counts the picks it computed (``moe_held_picks``),
+those it could not (``moe_dropped``, 0 by construction) and the largest
+held expert's picks (``moe_load_max``).
+
+Router aux loss is the standard load-balancing loss (Shazeer/Switch) over
+every expert: ``E * sum_e f_e * P_e`` with f the share of the layer's picks
+and P the mean router probability.
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import MOE_COMBINE, MOE_DISPATCH, MOE_EXPERTS, MOE_ROUTE, scope
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, mlp_apply, mlp_init
 
 Tree = Dict[str, jax.Array]
-
-# Sharding profile: SPMD propagation cannot see through the scatter
-# dispatch, so the launcher pins the expert-parallel layout explicitly
-# (see repro.models.shard_ctx; re-exported here for the launcher).
-from repro.models.shard_ctx import (  # noqa: E402
-    constrain as _constrain,
-    get_profile as _get_profile,
-    shard_profile,
-)
+COUNTERS = ("moe_held_picks", "moe_dropped", "moe_load_max")
 
 
 def moe_init(rng, cfg: ModelConfig, dtype) -> Tree:
+    """Router over all experts; gate/up (H, d, ff) and down (H, ff, d) of the
+    H held experts. Expert ``e``'s weights come from ``fold_in(rng, e)``, so
+    a share's weights are the uncut layer's rows for those experts."""
     kr, ke, ks = jax.random.split(rng, 3)
-    d, ff, E = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
-    scale = d**-0.5
+    d, ff, H = cfg.d_model, cfg.moe_d_ff, cfg.held_experts
+    experts = cfg.expert_shard * H + jnp.arange(H)
+
+    def stacked(i, shape, scale):
+        keys = jax.vmap(lambda e: jax.random.fold_in(jax.random.fold_in(ke, i), e))(experts)
+        w = jax.vmap(lambda k: jax.random.normal(k, shape, jnp.float32))(keys)
+        return w.astype(dtype) * scale
+
     p: Tree = {
-        "router": dense_init(kr, d, E, jnp.float32),  # router math stays f32
-        # stacked expert weights: (E, d, ff) x2 + (E, ff, d)
-        "gate": jax.random.normal(ke, (E, d, ff), jnp.float32).astype(dtype) * scale,
-        "up": jax.random.normal(
-            jax.random.fold_in(ke, 1), (E, d, ff), jnp.float32
-        ).astype(dtype)
-        * scale,
-        "down": jax.random.normal(
-            jax.random.fold_in(ke, 2), (E, ff, d), jnp.float32
-        ).astype(dtype)
-        * (ff**-0.5),
+        "router": dense_init(kr, d, cfg.num_experts, jnp.float32),  # router math stays f32
+        "gate": stacked(0, (d, ff), d**-0.5),
+        "up": stacked(1, (d, ff), d**-0.5),
+        "down": stacked(2, (ff, d), ff**-0.5),
     }
     if cfg.shared_expert:
         p["shared"] = mlp_init(ks, d, cfg.d_ff, dtype)
     return p
-
-
-ROUTE_BLOCK = 2048  # tokens per routing block (capacity enforced per block)
-
-
-def _capacity(n_tokens: int, cfg: ModelConfig) -> int:
-    cap = int(cfg.capacity_factor * n_tokens * cfg.experts_per_token / cfg.num_experts)
-    # MXU alignment: round the expert batch up to a lane multiple
-    return max(8, -(-cap // 8) * 8)
 
 
 def router_probs(p: Tree, x: jax.Array) -> jax.Array:
@@ -72,179 +67,121 @@ def router_probs(p: Tree, x: jax.Array) -> jax.Array:
     return jax.nn.softmax(logits, axis=-1)
 
 
-def _route_block(p: Tree, xf: jax.Array, cfg: ModelConfig, C: int):
-    """Route one token block. xf: (N, d) -> (buf (E,C,d), combine metadata).
-
-    Block-LOCAL by construction: under auto-SPMD the vmapped caller shards
-    the block dim across (pod, data, model), so the sort, the scatter and the
-    (E, C, d) packed buffer all stay device-local — no global sort, no
-    E x C_global buffer (DESIGN.md: TPU adaptation of the GPU ragged
-    dispatch).
-    """
-    N, d = xf.shape
-    E, k = cfg.num_experts, cfg.experts_per_token
-
-    probs = router_probs(p, xf)  # (N, E) f32
-    top_w, top_e = jax.lax.top_k(probs, k)  # (N, k)
-    top_w = top_w / jnp.maximum(jnp.sum(top_w, axis=-1, keepdims=True), 1e-9)
-
-    # one-hot slot->expert (partitioner-friendly: no sort / searchsorted /
-    # data-dependent gathers, which force SPMD "involuntary full remat")
-    oh = jax.nn.one_hot(top_e, E, dtype=jnp.int32)  # (N, k, E)
-    ohf = oh.reshape(N * k, E)
-
-    # load-balancing aux loss (per block)
-    frac = jnp.mean(jnp.sum(oh, axis=1).astype(jnp.float32), axis=0) / k
-    aux = E * jnp.sum(frac * jnp.mean(probs, axis=0))
-
-    # capacity assignment: rank of each slot within its expert = running
-    # count of earlier same-expert slots (cumsum of the one-hot)
-    ids = top_e.reshape(-1)  # (M,)
-    cum = jnp.cumsum(ohf, axis=0)  # (M, E)
-    rank = (
-        jnp.take_along_axis(cum, ids[:, None], axis=1)[:, 0] - 1
-    ).astype(jnp.int32)
-    keep = rank < C
-    safe_rank = jnp.where(keep, rank, 0)
-    safe_ids = jnp.where(keep, ids, 0)
-
-    buf = jnp.zeros((E, C, d), xf.dtype)
-    xf_rep = jnp.repeat(xf, k, axis=0)  # (M, d) — static slot->token map
-    contrib = jnp.where(keep[:, None], xf_rep, 0).astype(xf.dtype)
-    buf = buf.at[safe_ids, safe_rank].add(contrib)
-    w_flat = (top_w.reshape(-1) * keep).astype(jnp.float32)
-    return buf, (safe_ids, safe_rank, w_flat, aux)
+def zero_stats() -> Dict[str, jax.Array]:
+    """What one MoE layer reports, zeroed: the router aux loss and the
+    counters, to be summed over layers and steps."""
+    out = {"router_aux": jnp.float32(0.0)}
+    out.update({c: jnp.int32(0) for c in COUNTERS})
+    return out
 
 
-def _combine_block(out: jax.Array, meta, N: int, dtype):
-    safe_ids, safe_rank, w_flat, _ = meta
-    k = w_flat.shape[0] // N
-    gathered = out[safe_ids, safe_rank]  # (M, d) f32
-    y = jnp.einsum(
-        "nkd,nk->nd",
-        gathered.reshape(N, k, -1),
-        w_flat.reshape(N, k),
-    )
-    return y.astype(dtype)
+def _experts(xs, gate, up, down, sizes):
+    """The held experts' gated MLP over picks sorted by expert. Rows past
+    the groups' total are not computed: on the TPU they hold whatever the
+    buffer held, so every reader of them masks by selection, never by a
+    zero weight."""
+    dtype = xs.dtype
+    g = jax.lax.ragged_dot(xs, gate, sizes)
+    u = jax.lax.ragged_dot(xs, up, sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(dtype) * u
+    return jax.lax.ragged_dot(h, down, sizes)
 
 
-def _pin_ep(t: jax.Array, ep_lead) -> jax.Array:
-    if ep_lead is None:
-        return t
-    return _constrain(t, tuple(ep_lead) + (None,) * (t.ndim - len(ep_lead)))
+def _gather_sum(r, slot, held, w):
+    """(N, d): sum over the held picks j of ``w[n, j] * r[slot[n, j]]``,
+    added in f32; an absent pick's row is never read into the sum."""
+    N, k = slot.shape
+    picked = r[slot.reshape(-1)].reshape(N, k, -1).astype(jnp.float32)
+    return jnp.sum(jnp.where(held[..., None], picked * w[..., None], 0.0),
+                   axis=1).astype(r.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _expert_ffn(buf, gate, up, down, ep_lead):
-    """Expert FFN in the EP layout, with a hand-written VJP.
-
-    AD's default weight-gradient einsums transpose the (nb, E, C, d) buffer
-    into layouts the SPMD partitioner can only realize by full replication
-    (observed: 160 GiB f32 all-gathers in the dry-run). The custom VJP
-    writes each gradient contraction in the layout-preserving order and pins
-    the EP sharding on every operand, so weight grads are local partials +
-    an all-reduce over the block axis.
-    """
-    g = jnp.einsum("necd,edf->necf", buf, gate,
-                   preferred_element_type=jnp.float32)
-    u = jnp.einsum("necd,edf->necf", buf, up,
-                   preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g) * u).astype(buf.dtype)
-    return jnp.einsum("necf,efd->necd", h, down,
-                      preferred_element_type=jnp.float32).astype(buf.dtype)
+# Dispatch and combine move tokens to sorted rows and back. As plain
+# indexing, each one's backward would be a scatter; written as each other's
+# transpose, both directions are gathers and a token's picks sum in f32.
+@jax.custom_vjp
+def _dispatch(x, pick, slot, held):
+    """x: (N, d) -> (rows, d): row r holds the token of pick ``pick[r]``."""
+    return x[pick // slot.shape[1]]
 
 
-def _expert_ffn_fwd(buf, gate, up, down, ep_lead):
-    return _expert_ffn(buf, gate, up, down, ep_lead), (buf, gate, up, down)
+def _dispatch_fwd(x, pick, slot, held):
+    return _dispatch(x, pick, slot, held), (slot, held)
 
 
-def _expert_ffn_bwd(ep_lead, res, gbar):
-    buf, gate, up, down = res
-    gbar = _pin_ep(gbar.astype(jnp.float32), ep_lead)
-    # recompute activations (checkpoint-style: nothing stashed but inputs)
-    g = jnp.einsum("necd,edf->necf", buf, gate,
-                   preferred_element_type=jnp.float32)
-    u = jnp.einsum("necd,edf->necf", buf, up,
-                   preferred_element_type=jnp.float32)
-    sg = jax.nn.sigmoid(g)
-    silu_g = g * sg
-    h = silu_g * u
-    # d_down[e,f,d] = sum_{n,c} h * gbar   (partial over local blocks + psum)
-    d_down = jnp.einsum("necf,necd->efd", h, gbar,
-                        preferred_element_type=jnp.float32)
-    d_h = jnp.einsum("necd,efd->necf", gbar, down.astype(jnp.float32),
-                     preferred_element_type=jnp.float32)
-    d_u = d_h * silu_g
-    d_g = d_h * u * (sg + silu_g * (1.0 - sg))
-    d_gate = jnp.einsum("necd,necf->edf", buf, d_g,
-                        preferred_element_type=jnp.float32)
-    d_up = jnp.einsum("necd,necf->edf", buf, d_u,
-                      preferred_element_type=jnp.float32)
-    d_buf = jnp.einsum("necf,edf->necd", d_g, gate.astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
-    d_buf = d_buf + jnp.einsum("necf,edf->necd", d_u, up.astype(jnp.float32),
-                               preferred_element_type=jnp.float32)
-    d_buf = _pin_ep(d_buf, ep_lead).astype(buf.dtype)
-    return (
-        d_buf,
-        d_gate.astype(gate.dtype),
-        d_up.astype(up.dtype),
-        d_down.astype(down.dtype),
-    )
+def _dispatch_bwd(res, g):
+    slot, held = res
+    return _gather_sum(g, slot, held, jnp.ones(held.shape, jnp.float32)), None, None, None
 
 
-_expert_ffn.defvjp(_expert_ffn_fwd, _expert_ffn_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def moe_apply(p: Tree, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (y: (B, S, d), aux_loss: scalar f32)."""
+@jax.custom_vjp
+def _combine(out, w, held, pick, slot):
+    """out: (rows, d), w: (N, k) -> (N, d): each token's held picks weighted."""
+    return _gather_sum(out, slot, held, w)
+
+
+def _combine_fwd(out, w, held, pick, slot):
+    return _gather_sum(out, slot, held, w), (out, w, held, pick, slot)
+
+
+def _combine_bwd(res, g):
+    out, w, held, pick, slot = res
+    N, k = slot.shape
+    held_row = held.reshape(-1)[pick]  # rows of absent picks get nothing
+    w_row = jnp.where(held_row, w.reshape(-1)[pick], 0.0)
+    d_out = (g[pick // k].astype(jnp.float32) * w_row[:, None]).astype(out.dtype)
+    picked = out[slot.reshape(-1)].reshape(N, k, -1).astype(jnp.float32)
+    d_w = jnp.where(held, jnp.sum(picked * g.astype(jnp.float32)[:, None, :], axis=-1), 0.0)
+    return d_out, d_w, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def moe_apply(p: Tree, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
+    """x: (B, S, d) -> (y: (B, S, d), stats: ``zero_stats()``'s keys)."""
     B, S, d = x.shape
-    N = B * S
-    E, k = cfg.num_experts, cfg.experts_per_token
-    prof = _get_profile()
-    min_blocks = prof["min_blocks"] if prof else 1
-    # block count must be a multiple of the devices the block dim shards over
-    if N % min_blocks == 0 and N // min_blocks >= 8:
-        nb = min_blocks * max(1, N // (ROUTE_BLOCK * min_blocks))
-    else:
-        nb = max(1, N // ROUTE_BLOCK)
-    while N % nb:
-        nb -= 1
-    block = N // nb
-    C = _capacity(block, cfg)
-    xb = x.reshape(nb, block, d)
+    N, E, k, H = B * S, cfg.num_experts, cfg.experts_per_token, cfg.held_experts
+    M, rows = N * k, N * min(k, H)
+    xf = x.reshape(N, d)
 
-    buf, meta = jax.vmap(
-        lambda xf: _route_block(p, xf, cfg, C)
-    )(xb)  # buf: (nb, E, C, d)
+    with scope(MOE_ROUTE):
+        probs = router_probs(p, xf)  # (N, E) f32
+        top_w, top_e = jax.lax.top_k(probs, k)  # (N, k)
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        frac = jnp.zeros((E,), jnp.float32).at[top_e.reshape(-1)].add(1.0) / M
+        aux = E * jnp.sum(frac * jnp.mean(probs, axis=0))
 
-    # expert FFN — dense einsums; E shards on `model` (expert parallel), the
-    # block dim shards on the batch axes. The dispatch->EP reshard (blocks
-    # stay on their devices, experts move to theirs) is the all-to-all of a
-    # classic EP implementation, made explicit for the SPMD partitioner.
-    prof = _get_profile()
-    if prof is not None:
-        ba, ep = prof["batch"], prof["expert"]
-        # 1. pin the scatter output to the dispatch layout (blocks stay put);
-        #    without this the partitioner replicates through the scatter
-        buf = _constrain(buf, (ba or None, None, None, None))
-        # 2. explicit reshard to the expert-parallel layout (the EP
-        #    all-to-all): blocks give up the expert axis, experts localize
-        nb_axes = tuple(a for a in ba if a != ep) or None
-        buf = _constrain(buf, (nb_axes, ep, None, None))
-    ep_lead = None
-    if prof is not None:
-        ep_lead = (nb_axes, prof["expert"])
-    out = _expert_ffn(buf, p["gate"], p["up"], p["down"], ep_lead)
-    if prof is not None:
-        out = _constrain(out, (ba or None, None, None, None))
+    with scope(MOE_DISPATCH):
+        local = top_e - cfg.expert_shard * H  # (N, k) index among held
+        held = (local >= 0) & (local < H)
+        group = jnp.where(held, local, H).reshape(-1)  # absent picks sort last
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((H,), jnp.int32).at[group].add(1, mode="drop")
+        pick = order[:rows]  # the pick of each sorted row
+        # each pick's row; an absent pick past the buffer points at the last
+        # row, and ``held`` masks it out
+        slot = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
+        slot = jnp.minimum(slot, rows - 1).reshape(N, k)
+        xs = _dispatch(xf, pick, slot, held)
 
-    y = jax.vmap(
-        lambda o, m: _combine_block(o, m, block, x.dtype)
-    )(out, meta)
+    with scope(MOE_EXPERTS):
+        out = _experts(xs, p["gate"], p["up"], p["down"], sizes)  # (rows, d)
+
+    with scope(MOE_COMBINE):
+        y = _combine(out, top_w, held, pick, slot)
+
     y = y.reshape(B, S, d)
-    aux = jnp.mean(meta[3])
-
     if cfg.shared_expert:
         y = y + mlp_apply(p["shared"], x, cfg.activation)
-    return y, aux
+    held_picks = jnp.sum(sizes)
+    stats = {
+        "router_aux": aux,
+        "moe_held_picks": held_picks,
+        "moe_dropped": jnp.maximum(held_picks - rows, 0),
+        "moe_load_max": jnp.max(sizes),
+    }
+    return y, stats

@@ -3,11 +3,9 @@
 XLA's SPMD propagation loses the batch sharding through gathers, scatters
 and scan carries (observed as "involuntary full rematerialization" and
 replicated 100+ GiB remat stashes in the dry-run buffer assignment). The
-launcher activates a profile during tracing; model code pins the few
-layout-critical tensors:
-
-* activations (B, S, d) — batch over the profile's batch axes,
-* MoE dispatch buffers — block dim on batch axes, then the EP reshard.
+launcher activates a profile during tracing; model code pins the
+layout-critical tensors, the (B, S, d) activations, in their compute and
+stash layouts.
 
 On a 1-device mesh (tests) or with no profile active this is a no-op.
 """
@@ -25,18 +23,10 @@ _PROFILE: contextvars.ContextVar = contextvars.ContextVar(
 )
 
 
-def get_profile() -> Optional[dict]:
-    return _PROFILE.get()
-
-
 @contextlib.contextmanager
-def shard_profile(batch_axes: Tuple[str, ...], expert_axis: str = "model",
-                  min_blocks: int = 1, act=None, stash=None,
-                  axis_sizes=None):
+def shard_profile(act=None, stash=None, axis_sizes=None):
     """Activate sharding constraints during tracing.
 
-    ``batch_axes``: mesh axes the flat MoE block dim spans.
-    ``min_blocks``: devices the MoE block dim shards over.
     ``act``: per-dim axes for (B, S, d) activations in the COMPUTE layout,
     e.g. ``(("data", "model"), None)``. ``stash``: the layout for scan
     carries / remat stashes, e.g. ``(("data",), ("model",))`` — sequence-
@@ -45,9 +35,7 @@ def shard_profile(batch_axes: Tuple[str, ...], expert_axis: str = "model",
     ``axis_sizes``: {axis: size} for divisibility guards.
     """
     token = _PROFILE.set(
-        {"batch": tuple(batch_axes), "expert": expert_axis,
-         "min_blocks": int(min_blocks), "act": act, "stash": stash,
-         "axis_sizes": dict(axis_sizes or {})}
+        {"act": act, "stash": stash, "axis_sizes": dict(axis_sizes or {})}
     )
     try:
         yield

@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.spans import LM_CE, scope
 from repro.models.blocks import (
     block_apply,
     block_cache_init,
@@ -30,6 +31,7 @@ from repro.models.layers import (
     rmsnorm_init,
     unembed_apply,
 )
+from repro.models.moe import zero_stats
 from repro.models.shard_ctx import pin_activation, pin_stash
 
 Tree = Any
@@ -110,6 +112,11 @@ def default_positions(cfg: ModelConfig, batch: int, seq: int, offset=0) -> jax.A
     return pos
 
 
+def _add_stats(total: Dict[str, jax.Array], stats: Dict[str, jax.Array]):
+    """``total`` with a block's ``stats`` added, key by key."""
+    return {k: v + stats[k] if k in stats else v for k, v in total.items()}
+
+
 def apply_stack(
     params: Tree,
     h: jax.Array,
@@ -117,14 +124,15 @@ def apply_stack(
     cache: Optional[Tree],
     mode: str,
     cfg: ModelConfig,
-) -> Tuple[jax.Array, Optional[Tree], jax.Array]:
+) -> Tuple[jax.Array, Optional[Tree], Dict[str, jax.Array]]:
+    """Returns (h, new_cache, stats summed over layers)."""
     kinds = layer_kinds(cfg)
-    aux = jnp.float32(0.0)
+    stats = zero_stats() if cfg.is_moe else {}
     if cfg.scan_layers:
         c_groups = cache["groups"] if cache is not None else None
 
         def body(carry, xs):
-            h, aux = carry
+            h, stats = carry
             h = pin_activation(h)  # scan carries lose the batch sharding
             if cache is not None:
                 p_slices, c_slices = xs
@@ -133,36 +141,36 @@ def apply_stack(
             new_c = []
             for j, kind in enumerate(kinds):
                 cj = None if c_slices is None else c_slices[j]
-                h, cj_new, a = block_apply(p_slices[j], h, positions, cj, mode, cfg, kind)
+                h, cj_new, s = block_apply(p_slices[j], h, positions, cj, mode, cfg, kind)
                 new_c.append(cj_new if cj_new is not None else 0)
-                aux = aux + a
+                stats = _add_stats(stats, s)
             out = tuple(new_c) if cache is not None else 0
             # carries / remat residuals live in the (sequence-sharded)
             # stash layout between iterations
-            return (pin_stash(h), aux), out
+            return (pin_stash(h), stats), out
 
         if cfg.remat:
             body = jax.checkpoint(body)
         xs = (params["groups"], c_groups) if cache is not None else params["groups"]
-        (h, aux), scanned = jax.lax.scan(body, (h, aux), xs)
+        (h, stats), scanned = jax.lax.scan(body, (h, stats), xs)
         new_cache = None
         if cache is not None:
             new_cache = dict(cache)
             new_cache["groups"] = scanned
-        return h, new_cache, aux
+        return h, new_cache, stats
 
     new_layers = []
     for i, p in enumerate(params["layers"]):
         kind = kinds[i % len(kinds)]
         ci = cache["layers"][i] if cache is not None else None
-        h, ci_new, a = block_apply(p, h, positions, ci, mode, cfg, kind)
+        h, ci_new, s = block_apply(p, h, positions, ci, mode, cfg, kind)
         new_layers.append(ci_new)
-        aux = aux + a
+        stats = _add_stats(stats, s)
     new_cache = None
     if cache is not None:
         new_cache = dict(cache)
         new_cache["layers"] = tuple(new_layers)
-    return h, new_cache, aux
+    return h, new_cache, stats
 
 
 def forward_hidden(
@@ -174,7 +182,7 @@ def forward_hidden(
     cache: Optional[Tree] = None,
     mode: str = "full",
 ) -> Tuple[jax.Array, Optional[Tree], jax.Array]:
-    """Returns (final-norm hidden (B,S,d), new_cache, aux_loss)."""
+    """Returns (final-norm hidden (B,S,d), new_cache, stats)."""
     if embeds is None:
         assert tokens is not None
         h = embed_apply(params["embed"], tokens)
@@ -185,11 +193,11 @@ def forward_hidden(
     if positions is None:
         offset = cache["len"] if (cache is not None and mode == "decode") else 0
         positions = default_positions(cfg, B, S, offset=offset)
-    h, new_cache, aux = apply_stack(params, h, positions, cache, mode, cfg)
+    h, new_cache, stats = apply_stack(params, h, positions, cache, mode, cfg)
     if new_cache is not None:
         new_cache["len"] = (cache["len"] if cache is not None else 0) + S
     h = rmsnorm_apply(params["ln_f"], h, cfg.norm_eps)
-    return h, new_cache, aux
+    return h, new_cache, stats
 
 
 def forward(
@@ -201,59 +209,68 @@ def forward(
     cache: Optional[Tree] = None,
     mode: str = "full",
 ) -> Tuple[jax.Array, Optional[Tree], jax.Array]:
-    """Returns (logits (B,S,V) f32, new_cache, aux_loss)."""
-    h, new_cache, aux = forward_hidden(
+    """Returns (logits (B,S,V) f32, new_cache, stats)."""
+    h, new_cache, stats = forward_hidden(
         params, cfg, tokens=tokens, embeds=embeds, positions=positions,
         cache=cache, mode=mode,
     )
     unemb = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = unembed_apply(unemb, h)
     # API boundary: drop the vocab padding rows (cfg.padded_vocab)
-    return logits[..., : cfg.vocab_size], new_cache, aux
+    return logits[..., : cfg.vocab_size], new_cache, stats
 
 
 def chunked_ce(
     h: jax.Array,  # (B, S, d) — hidden states for positions predicting t+1
     unemb: Tree,
     targets: jax.Array,  # (B, S) int32
+    vocab_size: int,
     *,
     n_chunks: int = 16,
     use_scan: bool = True,
 ) -> jax.Array:
-    """Cross-entropy without materializing full (B*S, V) f32 logits.
+    """Mean cross-entropy over the first ``vocab_size`` rows of the
+    unembedding without materializing full (B*S, V) f32 logits.
 
-    Flattens tokens and scans over ``n_chunks`` blocks: each block computes
+    The rows past ``vocab_size`` pad the table (``ModelConfig.padded_vocab``)
+    and are masked out of the softmax, so they take no probability and get
+    no gradient. Flattens tokens and scans over ``n_chunks`` blocks, padded
+    with rows of weight 0 to a whole number of blocks: each block computes
     (chunk, V) logits, a log-sum-exp and the target gather, keeping one
     block's logits live (the f32 logits of a 1M-token global batch against a
     150k vocab would otherwise be hundreds of TB)."""
     B, S, d = h.shape
     N = B * S
-    hf = h.reshape(N, d)
-    tf = targets.reshape(N)
-    if N % n_chunks:
+    if not use_scan:
         n_chunks = 1
-    chunk = N // n_chunks
+    pad = -N % n_chunks
+    hf = jnp.pad(h.reshape(N, d), ((0, pad), (0, 0)))
+    tf = jnp.pad(targets.reshape(N), (0, pad))
+    valid = jnp.arange(N + pad) < N
+    chunk = (N + pad) // n_chunks
 
-    def chunk_nll(hc, tc):
+    def chunk_nll(hc, tc, vc):
         logits = unembed_apply(unemb, hc)  # (chunk, V) f32
+        real = jnp.arange(logits.shape[-1]) < vocab_size
+        logits = jnp.where(real, logits, -jnp.inf)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        return jnp.sum(lse - picked)
+        return jnp.sum(jnp.where(vc, lse - picked, 0.0))
 
-    if use_scan and n_chunks > 1:
-        hs = hf.reshape(n_chunks, chunk, d)
-        ts = tf.reshape(n_chunks, chunk)
-        # recompute each chunk's logits in the backward instead of stashing
-        # (n_chunks, chunk, V) f32 scan residuals
-        ckpt_nll = jax.checkpoint(chunk_nll)
+    with scope(LM_CE):
+        if n_chunks > 1:
+            xs = (hf.reshape(n_chunks, chunk, d), tf.reshape(n_chunks, chunk),
+                  valid.reshape(n_chunks, chunk))
+            # recompute each chunk's logits in the backward instead of
+            # stashing (n_chunks, chunk, V) f32 scan residuals
+            ckpt_nll = jax.checkpoint(chunk_nll)
 
-        def body(tot, xs):
-            hc, tc = xs
-            return tot + ckpt_nll(hc, tc), None
+            def body(tot, xs):
+                return tot + ckpt_nll(*xs), None
 
-        total, _ = jax.lax.scan(body, jnp.float32(0.0), (hs, ts))
-    else:
-        total = chunk_nll(hf, tf)
+            total, _ = jax.lax.scan(body, jnp.float32(0.0), xs)
+        else:
+            total = chunk_nll(hf, tf, valid)
     return total / N
 
 
@@ -266,20 +283,26 @@ def lm_loss(
     tokens: jax.Array,
     embeds: Optional[jax.Array] = None,
     positions: Optional[jax.Array] = None,
-) -> jax.Array:
-    """Next-token cross-entropy (tokens shifted internally) + router aux.
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Next-token cross-entropy (tokens shifted internally) + router aux, and
+    the stack's counters (a MoE model's ``moe.COUNTERS``; none otherwise).
 
     Uses the chunked-CE path (scan) when the config is in deployment mode
     (``scan_attn_chunks``); the dry-run cost program unrolls to one matmul.
     """
-    h, _, aux = forward_hidden(
+    h, _, stats = forward_hidden(
         params, cfg, tokens=tokens, embeds=embeds, positions=positions
     )
     unemb = params["embed"] if cfg.tie_embeddings else params["unembed"]
     loss = chunked_ce(
-        h[:, :-1], unemb, tokens[:, 1:], use_scan=cfg.scan_attn_chunks
+        h[:, :-1], unemb, tokens[:, 1:], cfg.vocab_size,
+        use_scan=cfg.scan_attn_chunks,
     )
-    return loss + cfg.router_aux_weight * aux
+    counters = dict(stats)
+    aux = counters.pop("router_aux", None)
+    if aux is not None:
+        loss = loss + cfg.router_aux_weight * aux
+    return loss, counters
 
 
 def decode_step(

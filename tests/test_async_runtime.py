@@ -269,7 +269,7 @@ class TestFedStepParticipation:
         strat = get_strategy("fedavg")
 
         def loss_fn(p, batch, rng):
-            return jnp.mean((batch["x"] @ p["w"] - batch["y"]) ** 2)
+            return jnp.mean((batch["x"] @ p["w"] - batch["y"]) ** 2), {}
 
         step = make_fl_train_step(
             loss_fn, strat, plan, mesh,

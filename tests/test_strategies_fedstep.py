@@ -130,7 +130,7 @@ class TestFedStep:
 
         def loss_fn(p, batch, rng):
             pred = batch["x"] @ p["w"]
-            return jnp.mean((pred - batch["y"]) ** 2)
+            return jnp.mean((pred - batch["y"]) ** 2), {}
 
         step = make_fl_train_step(
             loss_fn, strat, plan, mesh,
