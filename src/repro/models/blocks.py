@@ -89,15 +89,10 @@ def _attn_core_full(
         q = _rotate(q, positions, cfg)
         k = _rotate(k, positions, cfg)
     with scope(ATTN_CORE):
-        if cfg.attn_impl == "flash":
-            from repro.kernels.flash_attention.ops import flash_attention
-
-            out = flash_attention(q, k, v, causal=True, window=cfg.sliding_window)
-        else:
-            out = attn.chunked_attention(
-                q, k, v, causal=True, window=cfg.sliding_window,
-                q_chunk=cfg.q_chunk, use_scan=cfg.scan_attn_chunks,
-            )
+        out = attn.attention(
+            q, k, v, causal=True, window=cfg.sliding_window,
+            q_chunk=cfg.q_chunk, use_scan=cfg.scan_attn_chunks,
+        )
     new_cache = None
     if cache is not None:
         S = h.shape[1]

@@ -81,7 +81,6 @@ class ModelConfig:
     # lax.scan over attention query chunks (bounds live score buffers to one
     # chunk — deployment/memory path) vs unrolled (exact cost accounting)
     scan_attn_chunks: bool = False
-    attn_impl: str = "xla"  # xla | flash (Pallas, TPU target)
     remat: bool = False  # activation checkpointing around each block
 
     # --- FL mapping (DESIGN.md §5: which mesh axes host FL clients) ---

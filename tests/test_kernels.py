@@ -7,8 +7,6 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels.agg.ops import aggregate_flat, aggregate_tree
 from repro.kernels.agg.ref import reference_aggregate
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import reference_attention
 from repro.kernels.quant.ops import (
     compress_tree,
     decompress_tree,
@@ -16,55 +14,84 @@ from repro.kernels.quant.ops import (
     quantize_flat,
 )
 from repro.kernels.quant.ref import reference_quantize
+from repro.models.attention import (
+    attention,
+    chunked_attention,
+    fused_applies,
+    fused_attention,
+)
 
 
-class TestFlashAttention:
+def _qkv(B, S, H, Hkv, D, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (B, S, H, D), dtype),
+            jax.random.normal(ks[1], (B, S, Hkv, D), dtype),
+            jax.random.normal(ks[2], (B, S, Hkv, D), dtype))
+
+
+class TestFusedAttention:
+    """The fused causal kernel (splash attention, interpret mode) against
+    the chunked scan it replaces on the TPU."""
+
     @pytest.mark.parametrize(
-        "B,S,H,Hkv,D,causal,window",
+        "B,S,H,Hkv,dtype,atol",
         [
-            (2, 128, 4, 2, 64, True, 0),    # GQA
-            (1, 256, 4, 4, 32, True, 0),    # MHA
-            (2, 192, 8, 1, 64, True, 64),   # MQA + sliding window
-            (1, 128, 4, 2, 64, False, 0),   # bidirectional (encoder)
-            (1, 200, 2, 2, 32, True, 0),    # unpadded -> padding path
+            (2, 256, 4, 2, jnp.float32, 2e-5),     # GQA
+            (1, 256, 4, 4, jnp.float32, 2e-5),     # MHA
+            (1, 256, 8, 1, jnp.float32, 2e-5),     # MQA
+            (1, 384, 4, 2, jnp.float32, 2e-5),     # GQA over 3 x 3 blocks
+            (1, 256, 4, 2, jnp.bfloat16, 3e-2),    # bf16 operands
         ],
+        ids=["gqa", "mha", "mqa", "gqa-three-blocks", "bf16"],
     )
-    def test_against_reference(self, B, S, H, Hkv, D, causal, window):
-        ks = jax.random.split(jax.random.key(S + H + window), 3)
-        q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
-        k = jax.random.normal(ks[1], (B, S, Hkv, D), jnp.float32)
-        v = jax.random.normal(ks[2], (B, S, Hkv, D), jnp.float32)
-        out = flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=64, block_k=64)
-        ref = reference_attention(q, k, v, causal=causal, window=window)
-        np.testing.assert_allclose(out, ref, atol=2e-5)
-
-    def test_bf16_dtype(self):
-        ks = jax.random.split(jax.random.key(0), 3)
-        q = jax.random.normal(ks[0], (1, 128, 4, 64), jnp.bfloat16)
-        k = jax.random.normal(ks[1], (1, 128, 2, 64), jnp.bfloat16)
-        v = jax.random.normal(ks[2], (1, 128, 2, 64), jnp.bfloat16)
-        out = flash_attention(q, k, v, block_q=64, block_k=64)
-        ref = reference_attention(q, k, v)
-        assert out.dtype == jnp.bfloat16
+    def test_forward_matches_chunked(self, B, S, H, Hkv, dtype, atol):
+        q, k, v = _qkv(B, S, H, Hkv, 128, dtype)
+        out = fused_attention(q, k, v, interpret=True)
+        ref = chunked_attention(q, k, v, causal=True)
+        assert out.dtype == dtype
         np.testing.assert_allclose(
-            out.astype(np.float32), ref.astype(np.float32), atol=3e-2
-        )
+            out.astype(np.float32), ref.astype(np.float32), atol=atol)
 
-    @settings(max_examples=8, deadline=None)
-    @given(
-        S=st.sampled_from([64, 96, 160]),
-        D=st.sampled_from([16, 32]),
-        block=st.sampled_from([32, 64]),
+    @pytest.mark.parametrize(
+        "B,S,H,Hkv", [(2, 256, 4, 2), (1, 256, 8, 1)], ids=["gqa", "mqa"])
+    def test_gradients_match_chunked(self, B, S, H, Hkv):
+        q, k, v = _qkv(B, S, H, Hkv, 128, seed=1)
+        do = jax.random.normal(jax.random.key(2), q.shape)
+
+        def grads(fn):
+            return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * do),
+                            argnums=(0, 1, 2))(q, k, v)
+
+        fused = grads(lambda q, k, v: fused_attention(q, k, v, interpret=True))
+        for got, want in zip(fused, grads(chunked_attention)):
+            np.testing.assert_allclose(got, want, atol=5e-5)
+
+    @pytest.mark.parametrize(
+        "S,D,causal,window,q_offset,fused",
+        [
+            (256, 128, True, 0, 0, True),
+            (256, 128, True, 64, 0, False),     # sliding window
+            (320, 128, True, 0, 0, False),      # no block of whole lanes tiles it
+            (256, 64, True, 0, 0, False),       # head size under a lane row
+            (256, 128, False, 0, 0, False),     # bidirectional (encoder)
+            (256, 128, True, 0, 128, False),    # queries after a prefix
+        ],
+        ids=["fused", "window", "no-lane-block", "dh64", "not-causal",
+             "q-offset"],
     )
-    def test_block_shape_sweep(self, S, D, block):
-        ks = jax.random.split(jax.random.key(S * D), 3)
-        q = jax.random.normal(ks[0], (1, S, 2, D), jnp.float32)
-        k = jax.random.normal(ks[1], (1, S, 2, D), jnp.float32)
-        v = jax.random.normal(ks[2], (1, S, 2, D), jnp.float32)
-        out = flash_attention(q, k, v, block_q=block, block_k=block)
-        ref = reference_attention(q, k, v)
-        np.testing.assert_allclose(out, ref, atol=2e-5)
+    def test_dispatch(self, S, D, causal, window, q_offset, fused):
+        """Only the fused case stages the kernel out (for a TPU); each other
+        case is the chunked scan alone, and on the CPU every case computes
+        exactly what the chunked scan does."""
+        q, k, v = _qkv(1, S, 4, 2, D, seed=3)
+        kw = dict(causal=causal, window=window, q_offset=q_offset, q_chunk=128)
+        assert fused_applies(q, k, causal=causal, window=window,
+                             q_offset=q_offset) is fused
+        staged = str(jax.make_jaxpr(lambda q, k, v: attention(q, k, v, **kw))(q, k, v))
+        assert ("pallas_call" in staged) is fused
+        np.testing.assert_array_equal(
+            jax.jit(lambda q, k, v: attention(q, k, v, **kw))(q, k, v),
+            jax.jit(lambda q, k, v: chunked_attention(q, k, v, **kw))(q, k, v))
 
 
 class TestAggKernel:

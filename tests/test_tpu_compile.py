@@ -11,18 +11,25 @@ described inside a module fixture, never while a module is imported, and
 these compiles live in this one file. The persistent compile cache is off
 around them: an entry written for a described chip cannot be read back.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.agg.kernel import fold_scaled, weighted_aggregate
-from repro.kernels.flash_attention.kernel import flash_attention_bhsd
 from repro.kernels.quant.kernel import dequantize_blocks, quantize_blocks
 
 # parameters in one qwen2.5-3b decoder layer
 LAYER = 77_076_992
 QUANT_ELEMS = 64 * 2**20
+# the fused attention kernels: forward with its residuals, dq and dkv
+SPLASH_KERNELS = ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")
+# one local step of the Qwen3-235B-A22B step cell: 2 sequences of 8,192
+SEQS, SEQ = 2, 8192
 
 
 @pytest.fixture(scope="module")
@@ -97,19 +104,81 @@ def test_dequantize_blocks_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_flash_attention_compiles(one_chip):
-    # qwen2.5-3b heads: 16 query heads over 2 KV heads of 128
-    B, H, Hkv, S, D = 1, 16, 2, 4096, 128
-    compiled = _compile(
-        lambda q, k, v: flash_attention_bhsd(
-            q, k, v, causal=True, window=0, block_q=128, block_k=128,
-            interpret=False, kv_len=S,
-        ),
-        _spec(one_chip, (B, H, S, D), jnp.bfloat16),
-        _spec(one_chip, (B, Hkv, S, D), jnp.bfloat16),
-        _spec(one_chip, (B, Hkv, S, D), jnp.bfloat16),
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+def _qwen3_moe_cell(**changes):
+    """Qwen3-235B-A22B as the step cell runs it: 8 of 128 experts held, an
+    eighth of the vocabulary, attention scanned in chunks of 256 queries
+    where it is not fused, each layer rematerialised."""
+    from repro.configs import get_config
+
+    return dataclasses.replace(
+        get_config("qwen3_moe_235b_a22b"), experts_held=8, vocab_size=18_992,
+        fl_axes=("data",), scan_attn_chunks=True, q_chunk=256, remat=True,
+        **changes)
+
+
+def _assert_fused(text):
+    for kernel in SPLASH_KERNELS:
+        assert kernel in text, f"no {kernel} kernel in the compiled program"
+
+
+def test_fused_attention_compiles_in_the_attention_block(one_chip):
+    """Qwen3-235B-A22B's attention sub-block (64 query heads over 4 key
+    heads of 128) at the step cell's 2 x 8,192 tokens, forward and backward:
+    the TPU program runs the fused kernel and its two backward kernels."""
+    from repro.models.attention import attn_init
+    from repro.models.blocks import _attn_core_full
+
+    cfg = _qwen3_moe_cell()
+    params = jax.eval_shape(lambda k: attn_init(k, cfg, jnp.bfloat16),
+                            jax.random.key(0))
+    params = jax.tree_util.tree_map(
+        lambda s: _spec(one_chip, s.shape, s.dtype), params)
+    positions = _spec(one_chip, (SEQS, SEQ), jnp.int32)
+
+    def loss(p, h, pos):
+        out, _ = _attn_core_full(p, h, pos, None, cfg)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)), params,
+                        _spec(one_chip, (SEQS, SEQ, cfg.d_model), jnp.bfloat16),
+                        positions)
+    _assert_fused(compiled.as_text())
+
+
+def test_fused_attention_compiles_in_the_fl_train_step(one_chip):
+    """One layer of the step cell's TAG-lowered FedAvg train step (two local
+    steps of 2 x 8,192 tokens): attention runs as the fused kernels inside
+    the step's client shard_map, and no chunk's f32 scores, (..., 256,
+    8,192), are left in the program."""
+    from repro.fl.fedstep import FedStepConfig
+    from repro.launch import sharding as shd
+    from repro.launch.steps import build_train_step
+    from repro.launch.train import make_batch, make_mesh_for_devices
+
+    cfg = _qwen3_moe_cell(num_layers=1)
+    mesh = make_mesh_for_devices(list(one_chip.device_set))
+    bundle, setup = build_train_step(
+        cfg, mesh, FedStepConfig(local_steps=2, local_lr=0.01),
+        strategy_name="fedavg")
+    assert setup.client_axes == ("data",)
+
+    def with_shardings(shapes, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, shardings)
+
+    p_sh, s_sh, _, rng_sh = setup.in_shardings
+    params = jax.eval_shape(bundle.init, jax.random.key(0))
+    state = jax.eval_shape(setup.init_state, params)
+    host = make_batch(cfg, np.zeros((2 * SEQS, SEQ), np.int32))
+    batch = with_shardings(host, shd.batch_shardings(host, cfg, mesh))
+    rng = with_shardings(jax.eval_shape(lambda: jax.random.key(0)), rng_sh)
+    compiled = jax.jit(setup.step, out_shardings=setup.out_shardings).lower(
+        with_shardings(params, p_sh), with_shardings(state, s_sh), batch, rng,
+    ).compile()
+    text = compiled.as_text()
+    _assert_fused(text)
+    assert not re.search(r"f32\[[\d,]*256,8192\]", text)
 
 
 def test_held_expert_layer_compiles(one_chip):
