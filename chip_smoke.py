@@ -11,13 +11,14 @@ script then exits non-zero without printing the result line.
 A. The on-mesh FL train step, through the path of ``repro.launch.train``:
    qwen2.5-3b at its published widths with only the depth cut, on the
    ``(data=1, model=1)`` mesh with FedAdam, 5 steps on one fixed batch.
-B. The aggregator fold on the device: ``weighted_mean``, ``StreamingMean``
-   and the FedBuff batch flush over 8 client updates, each the size of one
-   qwen2.5-3b decoder layer, bit for bit against the sequential numpy fold.
+B. The aggregator fold on the device: ``weighted_mean`` and ``StreamingMean``
+   over 8 client updates, each the size of one qwen2.5-3b decoder layer, bit
+   for bit against the sequential numpy fold; the fold's ``scale`` and
+   ``add`` compiled with no multiply-add between them.
 C. The ``int8_blocks`` wire codec at the same size, against
    ``repro.kernels.quant.ref``.
-D. A seeded classical-FL TAG job on threads with ``fused_aggregation`` on,
-   byte-identical to the same job with it off.
+D. A seeded classical-FL TAG job on threads with the device fold forced on,
+   byte-identical to the same job with the host fold forced.
 
 ``--chips 4`` runs the step of phase A on a ``(data=4, model=1)`` mesh with
 FedAvg (one FL client per chip) and compares it with the four clients' local
@@ -35,6 +36,7 @@ import re
 import sys
 import time
 from pathlib import Path
+from unittest import mock
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
@@ -43,19 +45,14 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
+from repro.core import fold  # noqa: E402
 from repro.core.expansion import JobSpec  # noqa: E402
-from repro.core.roles import (  # noqa: E402
-    FUSED_AGG_MIN_ELEMS,
-    StreamingMean,
-    weighted_mean,
-)
 from repro.core.runtime import run_job  # noqa: E402
 from repro.core.tag import DatasetSpec  # noqa: E402
 from repro.core.topologies import classical_fl  # noqa: E402
 from repro.data.datasets import synthetic_lm_batches  # noqa: E402
 from repro.fl import strategies  # noqa: E402
 from repro.fl.fedstep import FedStepConfig, local_round  # noqa: E402
-from repro.kernels.agg import ops as agg_ops  # noqa: E402
 from repro.kernels.quant.ops import BLOCK, quantize_flat  # noqa: E402
 from repro.kernels.quant.ref import reference_quantize  # noqa: E402
 from repro.launch import sharding as shd  # noqa: E402
@@ -300,7 +297,7 @@ def make_updates(shapes, n_clients: int, seed: int):
 
 
 def sequential_fold(updates):
-    """The sequential numpy fold the roles' fused path must reproduce:
+    """The sequential numpy fold the roles' device fold must reproduce:
     scale each update by its sample count, add in client order, divide once
     by the Python-float total. Returns ``(mean, sum, total)``."""
     total, acc = 0.0, None
@@ -311,28 +308,33 @@ def sequential_fold(updates):
     return jax.tree_util.tree_map(lambda x: x / total, acc), acc, total
 
 
-def phase_fold(updates, fused=None) -> dict:
-    """``fused=None`` is the roles' own size-based dispatch."""
+def compiled_ops(fn, *args) -> set:
+    """The HLO op names of jitted ``fn`` compiled for ``args``."""
+    return set(re.findall(r"= \S+ (\w+)\(", fn.lower(*args).compile().as_text()))
+
+
+def phase_fold(updates) -> dict:
+    """The roles' own dispatch rule picks the path (``fold.device_fold``)."""
     leaves = jax.tree_util.tree_leaves(updates[0][0])
     elems = sum(leaf.size for leaf in leaves)
     n = len(updates)
-    auto_fused = agg_ops.fused_dispatch_default() and elems >= FUSED_AGG_MIN_ELEMS
+    auto_fused = fold.device_fold(updates[0][0])
     log(f"[B] {n} client updates of {elems:,} f32 elements "
-        f"({_mib(4 * elems)} each); auto dispatch picks the fused path: "
-        f"{auto_fused}")
+        f"({_mib(4 * elems)} each); the dispatch rule picks the device "
+        f"fold: {auto_fused}")
     ref_mean, ref_sum, ref_total = sequential_fold(updates)
 
-    compiled_before = agg_ops._fold_flat._cache_size()
+    compiled_before = fold.add._cache_size()
     t = time.perf_counter()
-    mean, total = weighted_mean(updates, fused=fused)
+    mean, total = fold.weighted_mean(updates)
     t_mean = time.perf_counter() - t
-    fold_ran = agg_ops._fold_flat._cache_size() > compiled_before
+    fold_ran = fold.add._cache_size() > compiled_before
     if total != ref_total or tree_bytes(mean) != tree_bytes(ref_mean):
         raise AssertionError("weighted_mean differs from the sequential fold")
     log(f"[B] weighted_mean: bit-identical to the sequential numpy fold; "
         f"device fold ran: {fold_ran}; {t_mean} s host clock")
 
-    stream = StreamingMean(fused=fused)
+    stream = fold.StreamingMean()
     t = time.perf_counter()
     for tree, w in updates:
         stream.fold(tree, w)
@@ -342,23 +344,7 @@ def phase_fold(updates, fused=None) -> dict:
         raise AssertionError("StreamingMean differs from the sequential fold")
     log(f"[B] StreamingMean: bit-identical; {t_stream} s host clock")
 
-    buff = strategies.get_strategy("fedbuff", buffer_size=n)
-    stale = [c % 4 for c in range(n)]
-    zeros = jax.tree_util.tree_map(np.zeros_like, updates[0][0])
-    t = time.perf_counter()
-    flushed = buff.accumulate_batch(
-        buff.init(zeros), [tree for tree, _ in updates], stale, fused=fused
-    )
-    t_flush = time.perf_counter() - t
-    acc = zeros
-    for (tree, _), s in zip(updates, stale):
-        w = np.asarray(buff.staleness_weight(np.int32(s)))
-        acc = jax.tree_util.tree_map(lambda a, d: a + w * d, acc, tree)
-    if tree_bytes(flushed["acc"]) != tree_bytes(acc):
-        raise AssertionError("FedBuff batch flush differs from the numpy chain")
-    log(f"[B] FedBuff batch flush: bit-identical; {t_flush} s host clock")
-
-    # why the exact fold divides on the host: the device's f32 division
+    # why the fold divides on the host: the device's f32 division
     big = max(jax.tree_util.tree_leaves(ref_sum), key=lambda x: x.size)
     on_device = np.asarray(jnp.asarray(big) / jnp.float32(ref_total))
     ulps = ulp_distance(on_device, big / ref_total)
@@ -366,12 +352,13 @@ def phase_fold(updates, fused=None) -> dict:
         f"{int(ulps.max())} ulp, {int((ulps > 0).sum())} of {ulps.size} "
         f"elements differ")
 
-    hlo = agg_ops._fold_flat.lower(
-        jax.ShapeDtypeStruct((n, elems), jnp.float32), interpret=None
-    ).compile().as_text()
-    kernel = "tpu_custom_call" in hlo
-    log(f"[B] compiled fold contains tpu_custom_call: {kernel}")
-    return {"auto_fused": auto_fused, "fold_ran": fold_ran, "kernel": kernel,
+    leaf = jax.ShapeDtypeStruct(big.shape, jnp.float32)
+    scale_ops = compiled_ops(fold.scale, leaf, jax.ShapeDtypeStruct((), jnp.float32))
+    add_ops = compiled_ops(fold.add, leaf, leaf)
+    no_fma = "add" not in scale_ops and "multiply" not in add_ops
+    log(f"[B] compiled scale ops {sorted(scale_ops)}, add ops "
+        f"{sorted(add_ops)}: no multiply-add between them: {no_fma}")
+    return {"auto_fused": auto_fused, "fold_ran": fold_ran, "no_fma": no_fma,
             "divide_max_ulp": int(ulps.max())}
 
 
@@ -440,26 +427,26 @@ def phase_job(rounds: int = 3) -> dict:
         "w": (0.01 * rng.normal(size=(32, 10))).astype(np.float32),
         "b": np.zeros((10,), np.float32),
     }
+    job = JobSpec(
+        tag=classical_fl(),
+        datasets=tuple(DatasetSpec(name=f"d{i}") for i in range(4)),
+        hyperparams={"rounds": rounds, "init_weights": w0},
+    )
     weights = {}
-    ran_before = strategies._add_scaled._cache_size()
+    ran_before = fold.add._cache_size()
     for fused in (True, False):
-        job = JobSpec(
-            tag=classical_fl(),
-            datasets=tuple(DatasetSpec(name=f"d{i}") for i in range(4)),
-            hyperparams={"rounds": rounds, "init_weights": w0,
-                         "fused_aggregation": fused},
-        )
-        res = run_job(job, program_overrides={"trainer": SeededSGDTrainer},
-                      timeout=120)
+        with mock.patch.object(fold, "device_fold", lambda tree, on=fused: on):
+            res = run_job(job, program_overrides={"trainer": SeededSGDTrainer},
+                          timeout=120)
         if res.errors:
             raise AssertionError(f"job failed: {res.errors}")
         weights[fused] = res.global_weights()
-    device_fold = strategies._add_scaled._cache_size() > ran_before
+    device_fold = fold.add._cache_size() > ran_before
     same = tree_bytes(weights[True]) == tree_bytes(weights[False])
     log(f"[D] classical_fl, 4 trainers, {rounds} rounds: device fold ran: "
         f"{device_fold}; global weights fold-on == fold-off: {same}")
     if not same:
-        raise AssertionError("fused job weights differ from the sequential job")
+        raise AssertionError("device-fold job weights differ from the host-fold job")
     return {"device_fold": device_fold}
 
 
@@ -486,9 +473,9 @@ def main(argv=None) -> int:
     else:
         phase_train(cfg)
         updates = make_updates(layer_shapes(cfg), N_CLIENTS, seed=0)
-        fold = phase_fold(updates)
-        if not (fold["auto_fused"] and fold["fold_ran"] and fold["kernel"]):
-            raise AssertionError(f"the device fold did not run: {fold}")
+        folded = phase_fold(updates)
+        if not (folded["auto_fused"] and folded["fold_ran"] and folded["no_fma"]):
+            raise AssertionError(f"the device fold did not run: {folded}")
         if not phase_codec(updates[0][0])["kernel"]:
             raise AssertionError("the int8 quantizer did not run as a kernel")
         del updates

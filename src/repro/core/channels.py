@@ -49,6 +49,7 @@ from typing import (
 
 import numpy as np
 
+from repro.core.fold import StreamingMean
 from repro.core.spans import RECV, SEND, role_span, span
 from repro.core.tag import Channel as ChannelSpec
 
@@ -209,7 +210,6 @@ class TransportBackend(Protocol):
         dst: str,
         srcs: Sequence[str],
         shards: int = 1,
-        fused: Optional[bool] = None,
     ) -> None: ...
 
 
@@ -407,9 +407,7 @@ class ChannelEnd:
         self.send_many(self.ends(), msg)
 
     # --------------------------- reduce plane -------------------------- #
-    def install_reduce(
-        self, srcs: Sequence[str], shards: int = 1, fused: Optional[bool] = None
-    ) -> None:
+    def install_reduce(self, srcs: Sequence[str], shards: int = 1) -> None:
         """Install (or, with empty ``srcs``/``shards < 1``, remove) a
         hub-side reduce spec for this end's incast.
 
@@ -421,7 +419,7 @@ class ChannelEnd:
         untouched; the folded frames surface in ``hub_reduced:`` /
         ``hub_partials:`` counters."""
         self._backend.install_reduce(
-            self.channel, self.group, self.me, list(srcs), int(shards), fused
+            self.channel, self.group, self.me, list(srcs), int(shards)
         )
 
     def recv_ordered(self, ends: Sequence[str], timeout: Optional[float] = 30.0):
@@ -500,9 +498,8 @@ class _ReduceState:
     mailbox backlog. When a block's cursor completes, one partial frame is
     emitted and the block resets for the next round."""
 
-    def __init__(self, blocks: List[List[str]], fused: Optional[bool]) -> None:
+    def __init__(self, blocks: List[List[str]]) -> None:
         self.blocks = blocks
-        self.fused = fused
         self.block_of: Dict[str, int] = {
             s: i for i, b in enumerate(blocks) for s in b
         }
@@ -659,7 +656,6 @@ class InprocBackend:
         dst: str,
         srcs: Sequence[str],
         shards: int = 1,
-        fused: Optional[bool] = None,
     ) -> None:
         """Install/replace (or remove) the reduce spec for one incast topic.
 
@@ -675,7 +671,7 @@ class InprocBackend:
             if not blocks:
                 self._reduce.pop(key, None)
             else:
-                self._reduce[key] = _ReduceState(blocks, fused)
+                self._reduce[key] = _ReduceState(blocks)
 
     def _reduce_ingest(
         self,
@@ -706,9 +702,7 @@ class InprocBackend:
         while cur < len(block) and block[cur] in state.pending[i]:
             upd, arr = state.pending[i].pop(block[cur])
             if state.acc[i] is None:
-                from repro.core.roles import StreamingMean
-
-                state.acc[i] = StreamingMean(fused=state.fused)
+                state.acc[i] = StreamingMean()
             state.acc[i].fold(upd["weights"], float(upd.get("num_samples", 1)))
             state.hwm[i] = max(state.hwm[i], arr)
             cur += 1

@@ -38,12 +38,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.composer import Composer, ComposerError, Tasklet
-from repro.core.roles import (
-    Role,
-    StreamingMean,
-    _fold_allreduce,
-    await_peer,
-)
+from repro.core.fold import StreamingMean, _fold_allreduce
+from repro.core.roles import Role, await_peer
 
 
 # ------------------------------------------------------------------ #
@@ -196,7 +192,7 @@ class WeightSync(RoundProtocol):
         plan = self._reduce_plan() if not role._work_done else 0
         blocks = channels_mod.reduce_blocks(dsts, plan) if plan else []
         if blocks:
-            end.install_reduce(dsts, plan, role.config.get("fused_aggregation"))
+            end.install_reduce(dsts, plan)
         elif getattr(self, "_reduce_blocks", None):
             end.install_reduce([], 0)  # plan gone or final round: uninstall
         self._reduce_blocks = blocks
@@ -207,7 +203,7 @@ class WeightSync(RoundProtocol):
         if role._work_done:
             return  # peers were just told to exit; nothing will arrive
         end = self._end()
-        acc = StreamingMean(fused=role.config.get("fused_aggregation"))
+        acc = StreamingMean()
         blocks = getattr(self, "_reduce_blocks", None)
         if blocks:
             # hub-reduced incast: the broker already folded each shard's

@@ -13,14 +13,14 @@ from __future__ import annotations
 import abc
 import queue
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
-
-import numpy as np
+from typing import Any, Dict, List, Optional, Tuple
 
 from repro.core.channels import ChannelEnd, ChannelManager
 from repro.core.composer import CloneComposer, Composer, Loop, Tasklet
 from repro.core.expansion import WorkerConfig
-from repro.core.spans import FOLD_ADD, FOLD_FINALIZE, FOLD_PARTIAL, FOLD_SCALE, span
+# the fold's mean is part of the role API: StreamingMean and weighted_mean
+# are named here too
+from repro.core.fold import StreamingMean, _fold_allreduce, weighted_mean  # noqa: F401
 from repro.core.tag import TAG
 
 
@@ -112,291 +112,6 @@ def await_peer(ctx: "RoleContext", end: "ChannelEnd", timeout: float = 5.0) -> s
                 "(did the only upstream worker drop without a re-join?)"
             )
         time.sleep(0.01)
-
-
-# payloads at least this many elements take the fused Pallas reduction in
-# weighted_mean; below it the per-client numpy loop wins on dispatch
-# overhead. Both paths produce bit-identical results (the kernel's exact
-# mode reproduces sequential IEEE accumulation), so the threshold is purely
-# a performance knob — it can never change a job's numerics.
-FUSED_AGG_MIN_ELEMS = 16_384
-
-
-def _fused_weighted_mean(
-    updates: Sequence[Tuple[Any, float]], total: float
-) -> Optional[Any]:
-    """One stacked ``repro.kernels.agg.aggregate_tree`` call over all client
-    trees (exact mode: bit-identical to the sequential fold). Returns None
-    when the updates aren't uniform float32 trees (structure, shapes and
-    dtypes all match) — the caller falls back to the sequential path."""
-    import jax
-
-    from repro.kernels.agg.ops import aggregate_tree, stack_client_trees
-
-    client_trees = stack_client_trees([w for w, _ in updates])
-    if client_trees is None:
-        return None
-    w = np.asarray([float(n) for _, n in updates], np.float32)
-    agg = aggregate_tree(client_trees, w, denom=total, exact=True)
-    return jax.tree_util.tree_map(np.asarray, agg)
-
-
-def weighted_mean(
-    updates: Sequence[Tuple[Any, float]],
-    *,
-    fused: Optional[bool] = None,
-) -> Tuple[Optional[Any], float]:
-    """Sample-weighted mean of client model pytrees.
-
-    Returns ``(mean_tree, total_samples)``; ``(None, 0.0)`` when no update
-    carries positive weight. Shared by every aggregator-style role so the
-    accumulate/normalize logic exists exactly once.
-
-    Large float32 payloads are reduced by one stacked Pallas kernel call
-    (``repro.kernels.agg``) instead of a per-client Python ``tree_map``
-    loop; the kernel's exact mode folds in the callers' client order, so
-    fused and sequential results are bit-identical and ``fused`` (None =
-    auto: fused on accelerators for large payloads, sequential on CPU
-    where the numpy loop is already the fast path) is purely a performance
-    switch — it can never change a job's numerics.
-    """
-    import jax
-
-    total = 0.0
-    for _, n in updates:
-        total += n
-    if not updates or total <= 0:
-        return None, 0.0
-
-    if fused is None:
-        from repro.kernels.agg.ops import fused_dispatch_default
-
-        if fused_dispatch_default() and len(updates) > 1:
-            first = jax.tree_util.tree_leaves(updates[0][0])
-            elems = sum(int(np.size(leaf)) for leaf in first)
-            fused = elems >= FUSED_AGG_MIN_ELEMS
-        else:
-            fused = False
-    if fused:
-        mean = _fused_weighted_mean(updates, total)
-        if mean is not None:
-            return mean, total
-
-    acc = None
-    for weights, n in updates:
-        scaled = jax.tree_util.tree_map(lambda x: np.asarray(x) * n, weights)
-        acc = scaled if acc is None else jax.tree_util.tree_map(np.add, acc, scaled)
-    if acc is None:
-        return None, 0.0
-    return jax.tree_util.tree_map(lambda x: x / total, acc), total
-
-
-def _device_free_bytes() -> Optional[int]:
-    """Bytes free on the device jitted calls run on, or ``None`` where it
-    does not say (the CPU)."""
-    import jax
-
-    stats = jax.local_devices()[0].memory_stats()
-    if not stats or "bytes_limit" not in stats:
-        return None
-    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
-
-
-class StreamingMean:
-    """O(1)-memory streaming counterpart of ``weighted_mean``.
-
-    ``fold(weights, n)`` absorbs one client update at a time — callers feed
-    updates in sorted-src order — and ``finalize()`` returns
-    ``(mean_tree, total_samples)`` (``(None, 0.0)`` when nothing carried
-    positive weight). Only the running accumulator tree is retained: the
-    peak number of client update trees held at once is 1 regardless of
-    client count (``peak_buffered``).
-
-    Bit-identity: the per-update ``scale then add`` is the exact IEEE op
-    sequence of ``weighted_mean``'s sequential path — which the fused
-    exact-mode ``aggregate_tree`` kernel also reproduces — so for the same
-    fold order the streaming, buffered-sequential and buffered-fused
-    results are byte-identical. ``fused`` routes the per-update scale/add
-    through the separately-jitted pair from ``repro.fl.strategies`` (the
-    same no-FMA split as the kernel's exact mode); ``None`` auto-dispatches
-    like ``weighted_mean``.
-
-    On the fused path the accumulator stays on the device between folds and
-    is pulled to numpy once, when it is read (``finalize``, ``partial``,
-    ``fold_partial``), where the device has room for it beside a scaled
-    update: ``2·N`` plus two of its largest leaves free at the first fold,
-    for updates of ``N`` bytes. Without that room each sum goes back to the
-    host as it is made, and the device holds about ``N`` plus two leaves.
-    ``h2d_bytes`` counts the numpy leaves handed to a jitted call, and
-    ``d2h_bytes`` the device leaves turned back into numpy: for a round of
-    ``C`` updates ``C·N`` in and ``N`` out with the accumulator kept on the
-    device, ``(2·C − 1)·N`` in and ``C·N`` out without; 0 on the host path.
-    """
-
-    def __init__(self, fused: Optional[bool] = None) -> None:
-        self._fused = fused
-        self._acc: Any = None
-        self._resident: Optional[bool] = None
-        self._total = 0.0
-        self.count = 0
-        self.peak_buffered = 0
-        self.h2d_bytes = 0
-        self.d2h_bytes = 0
-
-    def _resolve_fused(self, weights: Any) -> bool:
-        import jax
-
-        if self._fused is None:
-            from repro.kernels.agg.ops import fused_dispatch_default
-
-            if fused_dispatch_default():
-                leaves = jax.tree_util.tree_leaves(weights)
-                elems = sum(int(np.size(leaf)) for leaf in leaves)
-                self._fused = elems >= FUSED_AGG_MIN_ELEMS
-            else:
-                self._fused = False
-        return bool(self._fused)
-
-    def fold(self, weights: Any, n: float) -> None:
-        import jax
-
-        n = float(n)
-        self._total += n
-        self.count += 1
-        self.peak_buffered = max(self.peak_buffered, 1)
-        if self._resolve_fused(weights):
-            from repro.fl.strategies import _add_scaled, _scale_delta
-
-            w = np.float32(n)
-            settle = (lambda x: x) if self._keeps_acc(weights) else self._to_host
-            with span(FOLD_SCALE):
-                leaves, treedef = jax.tree_util.tree_flatten(weights)
-                scaled = [_scale_delta(self._to_device(x), w) for x in leaves]
-            with span(FOLD_ADD):
-                if self._acc is None:
-                    acc = [settle(s) for s in scaled]
-                else:
-                    acc = treedef.flatten_up_to(self._acc)
-                    self._acc = None  # each old leaf is freed as its sum is made
-                    for i, s in enumerate(scaled):
-                        a = acc[i]
-                        if not isinstance(a, jax.Array):  # a host sum goes in
-                            a = self._to_device(a)
-                        acc[i] = settle(_add_scaled(a, s))
-                self._acc = treedef.unflatten(acc)
-            return
-        with span(FOLD_SCALE):
-            scaled = jax.tree_util.tree_map(lambda x: np.asarray(x) * n, weights)
-        with span(FOLD_ADD):
-            if self._acc is None:
-                self._acc = scaled
-            else:
-                self._acc = jax.tree_util.tree_map(np.add, self._acc, scaled)
-
-    def _keeps_acc(self, weights: Any) -> bool:
-        """Whether the fused accumulator stays on the device: decided at the
-        first fold, from the room the device has for it and a scaled update
-        (``2·N`` plus two of the largest leaves)."""
-        import jax
-
-        if self._resident is None:
-            sizes = [np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(weights)]
-            free = _device_free_bytes()
-            self._resident = free is None or 2 * sum(sizes) + 2 * max(sizes, default=0) <= free
-        return self._resident
-
-    def _to_device(self, x: Any) -> np.ndarray:
-        """``x`` as the numpy leaf a jitted call copies in, counted."""
-        x = np.asarray(x)
-        self.h2d_bytes += x.nbytes
-        return x
-
-    def _to_host(self, x: Any) -> np.ndarray:
-        """A jitted call's result pulled into numpy, counted."""
-        x = np.asarray(x)
-        self.d2h_bytes += x.nbytes
-        return x
-
-    def _host_acc(self) -> Any:
-        """The accumulator as numpy; device leaves are pulled once."""
-        import jax
-
-        self._acc = jax.tree_util.tree_map(
-            lambda x: self._to_host(x) if isinstance(x, jax.Array) else x, self._acc
-        )
-        return self._acc
-
-    def partial(self) -> Tuple[Optional[Any], float]:
-        """The raw running state: ``(weighted_sum_tree, total_weight)``.
-
-        This is the reduce plane's shard partial — unfinalized on purpose,
-        so a downstream fold over several partials can divide once by the
-        grand total exactly like :meth:`finalize` does, keeping the
-        one-shard case bit-identical to the per-frame streaming fold. The
-        tree is numpy: the hub packs it for the wire."""
-        return self._host_acc(), self._total
-
-    def fold_partial(self, acc: Any, total: float, count: int = 1) -> None:
-        """Absorb another accumulator's raw ``(acc, total)`` partial.
-
-        Partials are pre-scaled sums, so folding is a plain tree add (no
-        re-scaling); callers feed partials in sorted-shard order. ``count``
-        carries the number of source updates inside the partial so
-        ``self.count`` keeps meaning "updates folded"."""
-        import jax
-
-        if acc is None or count <= 0:
-            return
-        self._total += float(total)
-        self.count += int(count)
-        self.peak_buffered = max(self.peak_buffered, 1)
-        with span(FOLD_PARTIAL):
-            if self._acc is None:
-                self._acc = jax.tree_util.tree_map(np.asarray, acc)
-            else:
-                self._acc = jax.tree_util.tree_map(np.add, self._host_acc(), acc)
-
-    def finalize(self) -> Tuple[Optional[Any], float]:
-        import jax
-
-        if self._acc is None or self._total <= 0:
-            return None, 0.0
-        with span(FOLD_FINALIZE):
-            # f32 division on the v5e is not correctly rounded: divide on the host
-            mean = jax.tree_util.tree_map(lambda x: x / self._total, self._host_acc())
-        return mean, self._total
-
-
-def _fold_allreduce(
-    me: str,
-    own_weights: Any,
-    own_samples: float,
-    received: Sequence[Tuple[str, Any]],
-) -> Tuple[Any, int]:
-    """Sample-weighted mean of own + received models, folded in sorted
-    worker-id order so every ring member — on any transport backend, whatever
-    the arrival order — accumulates in the same sequence and lands on
-    byte-identical consensus weights."""
-    import jax
-
-    contributions = sorted(
-        [(me, {"weights": own_weights, "num_samples": own_samples})]
-        + list(received),
-        key=lambda t: t[0],
-    )
-    total = 0.0
-    acc = None
-    for _, msg in contributions:
-        n = float(msg.get("num_samples", 1))
-        total += n
-        scaled = jax.tree_util.tree_map(
-            lambda x: np.asarray(x, dtype=np.float64) * n, msg["weights"]
-        )
-        acc = scaled if acc is None else jax.tree_util.tree_map(np.add, acc, scaled)
-    mean = jax.tree_util.tree_map(
-        lambda a: (a / total).astype(np.float32), acc
-    )
-    return mean, int(total)
 
 
 class Role(abc.ABC):

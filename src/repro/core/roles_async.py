@@ -41,7 +41,8 @@ from repro import checkpoint
 from repro.core.channels import WorkerDropped, recv_any_multi
 from repro.core.composer import Composer, Loop, Tasklet
 from repro.core.protocols import pack_broadcast, pack_update
-from repro.core.roles import Role, StreamingMean, await_peer, bridge_clock
+from repro.core.fold import StreamingMean
+from repro.core.roles import Role, await_peer, bridge_clock
 
 
 def _tree_sub(a: Any, b: Any) -> Any:
@@ -289,7 +290,7 @@ class _DeadlineBase(_PolicyBase):
         # accumulator (the deadline window necessarily retains this round's
         # arrivals for on-time/late classification; the fold adds only one
         # more tree on top, not another O(C))
-        acc = StreamingMean(fused=self.config.get("fused_aggregation"))
+        acc = StreamingMean()
         for _, m, _ in sorted(on_time, key=lambda a: a[0]):
             acc.fold(m["weights"], float(m.get("num_samples", 1)))
         agg, total = acc.finalize()
@@ -465,10 +466,9 @@ class _BufferedAsyncBase(_PolicyBase):
         snapshot the sender trained from, and the weighted accumulation
         happens at arrival too — no delta tree is ever retained, so server
         memory is O(1) in client count and buffer size. The strategy's
-        ``ready`` check fires at exactly the moment the old deferred
-        buffer-flush fired (``count`` reaches the buffer size), and the
-        streaming fold is bit-identical to the flushed batch, so absorbed
-        versions and weights are unchanged."""
+        ``ready`` check fires when ``count`` reaches the buffer size, and
+        the streaming fold runs the ops of the incremental ``accumulate``
+        chain, so absorbed versions and weights are those of that chain."""
         # an unstamped update (sync-tier sender) counts as fresh, not maximal
         trained_from = int(msg.get("version", self._version))
         base, staleness, clamped = self._snapshots.base_for(
@@ -484,10 +484,7 @@ class _BufferedAsyncBase(_PolicyBase):
         self.staleness_log.append(entry)
         self.peak_buffered = max(self.peak_buffered, 1)
         self._strategy_state = self._strategy.accumulate_stream(
-            self._strategy_state,
-            delta,
-            int(staleness),
-            fused=self.config.get("fused_aggregation"),
+            self._strategy_state, delta, int(staleness)
         )
         if not bool(self._strategy.ready(self._strategy_state)):
             return False

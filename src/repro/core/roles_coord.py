@@ -24,8 +24,8 @@ from repro.core.roles import (
     Role,
     RoleContext,
     Trainer,
-    weighted_mean,
 )
+from repro.core.fold import weighted_mean
 
 COORD_TRAINER = "coord-trainer-channel"
 COORD_AGG = "coord-agg-channel"
@@ -111,9 +111,7 @@ class CoordAggregator(Aggregator):
             (msg["weights"], float(msg.get("num_samples", 1)))
             for _, msg in end.recv_fifo(self.assigned_trainers)
         ]
-        mean, total = weighted_mean(
-            updates, fused=self.config.get("fused_aggregation")
-        )
+        mean, total = weighted_mean(updates)
         if mean is not None:
             self.weights = mean
             self.agg_samples = int(total)
@@ -174,9 +172,7 @@ class CoordGlobalAggregator(GlobalAggregator):
             (msg["weights"], float(msg.get("num_samples", 1)))
             for _, msg in end.recv_fifo(self.active_aggs)
         ]
-        mean, _total = weighted_mean(
-            updates, fused=self.config.get("fused_aggregation")
-        )
+        mean, _total = weighted_mean(updates)
         if mean is not None:
             self.weights = mean
         self.metrics.append(

@@ -16,11 +16,13 @@ FedBuff     Nguyen et al. 2022           buffered async aggregation (K of N)
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core import fold
 
 Tree = Any
 
@@ -184,129 +186,29 @@ class FedDyn(ServerStrategy):
         return 0.5 * self.alpha * sum(jax.tree_util.tree_leaves(sq))
 
 
-def _fused_batch_sum(deltas: Sequence[Tree], weights: List[float]):
-    """``sum_i w_i * delta_i`` over uniform float32 delta trees via one
-    stacked exact-mode ``repro.kernels.agg`` call (scale pass compiled
-    separately from the add-only fold, so the result is bit-identical to the
-    sequential ``a + w*d`` chain). Returns None when the trees aren't
-    structurally eligible (mismatched treedefs, non-f32 leaves, ragged
-    shapes) — callers fall back to the incremental path, which produces the
-    same bits."""
-    from repro.kernels.agg.ops import aggregate_tree, stack_client_trees
-
-    tree = stack_client_trees(list(deltas))
-    if tree is None:
-        return None
-    w = np.asarray(weights, np.float32)
-    summed = aggregate_tree(tree, w, denom=1.0, exact=True)
-    return jax.tree_util.tree_map(np.asarray, summed)
-
-
-@jax.jit
-def _scale_delta(x: jax.Array, w: jax.Array) -> jax.Array:
-    # own jit entry, mirroring the exact-mode kernel split: compiling the
-    # scale together with the add would allow FMA contraction and break
-    # bit-equality with the eager ``a + w*d`` chain
-    return x * w
-
-
-@jax.jit
-def _add_scaled(a: jax.Array, s: jax.Array) -> jax.Array:
-    return a + s
-
-
 class _BufferedBatchMixin:
-    """Streaming / fused absorption for the buffered async strategies.
+    """Streaming absorption for the buffered async strategies.
 
     ``accumulate_stream(state, delta, staleness)`` folds ONE update into the
     strategy state the moment it arrives — the aggregator never buffers
-    delta trees, so server memory is O(1) in client count. The scale and the
-    add run as separate ops (separately-jitted on the fused path, eager
-    numpy-backed ops otherwise), which is the same IEEE op sequence as the
-    incremental ``accumulate`` chain: streaming is bit-identical to it by
-    construction.
-
-    ``accumulate_batch(state, deltas, staleness)`` absorbs a whole buffer of
-    updates (arrival order) at once: per-update staleness weights are
-    computed with the *same* scalar ops as the incremental ``accumulate``,
-    then the weighted sum runs as one stacked kernel call instead of one
-    Python ``tree_map`` pass per update. Bit-identical to calling
-    ``accumulate`` (or ``accumulate_stream``) in a loop — the fused path is
-    a performance switch, not a numerics change.
+    delta trees, so server memory is O(1) in client count. Where
+    ``fold.device_fold`` says so, the scale and the add run through the
+    fold's jitted ``scale``/``add`` pair; otherwise the eager ``accumulate``
+    chain runs. Both are the same IEEE op sequence, so the bits are the
+    same either way.
     """
 
     def _update_weight(self, staleness: jax.Array) -> jax.Array:
         raise NotImplementedError
 
-    def accumulate_stream(
-        self,
-        state: Tree,
-        delta: Tree,
-        staleness: int,
-        fused: Any = None,
-    ) -> Tree:
-        """Fold one arriving update into ``state`` (O(1) server memory).
-
-        ``fused=None`` auto-dispatches like ``weighted_mean``: the
-        separately-jitted scale/add pair on accelerators for large payloads,
-        the eager per-leaf ops otherwise. Both produce the same bits as the
-        incremental ``accumulate`` — the switch is purely about speed.
-        """
-        if fused is None:
-            from repro.core.roles import FUSED_AGG_MIN_ELEMS
-            from repro.kernels.agg.ops import fused_dispatch_default
-
-            elems = sum(
-                int(np.size(leaf)) for leaf in jax.tree_util.tree_leaves(delta)
-            )
-            fused = fused_dispatch_default() and elems >= FUSED_AGG_MIN_ELEMS
-        if not fused:
+    def accumulate_stream(self, state: Tree, delta: Tree, staleness: int) -> Tree:
+        """Fold one arriving update into ``state`` (O(1) server memory)."""
+        if not fold.device_fold(delta):
             return self.accumulate(state, delta, np.int32(staleness))
         w = self._update_weight(np.int32(staleness))
-        scaled = jax.tree_util.tree_map(lambda d: _scale_delta(d, w), delta)
-        acc = jax.tree_util.tree_map(_add_scaled, state["acc"], scaled)
+        scaled = jax.tree_util.tree_map(lambda d: fold.scale(d, w), delta)
+        acc = jax.tree_util.tree_map(fold.add, state["acc"], scaled)
         return {"acc": acc, "count": state["count"] + 1}
-
-    def accumulate_batch(
-        self,
-        state: Tree,
-        deltas: Sequence[Tree],
-        staleness: Sequence[int],
-        fused: Any = None,
-    ) -> Tree:
-        if not deltas:
-            return state
-        if fused is None:
-            from repro.core.roles import FUSED_AGG_MIN_ELEMS
-            from repro.kernels.agg.ops import fused_dispatch_default
-
-            elems = sum(
-                int(np.size(leaf))
-                for leaf in jax.tree_util.tree_leaves(deltas[0])
-            )
-            fused = fused_dispatch_default() and elems >= FUSED_AGG_MIN_ELEMS
-        summed = None
-        if fused and int(np.asarray(state["count"])) == 0:
-            ws = [
-                float(np.asarray(self._update_weight(np.int32(s))))
-                for s in staleness
-            ]
-            summed = _fused_batch_sum(deltas, ws)
-        if summed is not None:
-            # add into the (zeros) acc rather than replacing it: the
-            # incremental chain starts with ``0 + w_0*d_0``, which
-            # normalizes -0.0 to +0.0 — this add reproduces that exactly,
-            # keeping batch and incremental bit-identical on signed zeros
-            acc = jax.tree_util.tree_map(
-                lambda a, s: a + s, state["acc"], summed
-            )
-            return {
-                "acc": acc,
-                "count": state["count"] + np.int32(len(deltas)),
-            }
-        for d, s in zip(deltas, staleness):
-            state = self.accumulate(state, d, np.int32(s))
-        return state
 
 
 @dataclasses.dataclass

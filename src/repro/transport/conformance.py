@@ -473,7 +473,7 @@ def check_install_reduce_fold(factory: Factory) -> None:
     delivery, and an empty install uninstalls. Each comparison run lives on
     its own channel/workers so the check stays exact on shared-hub
     factories."""
-    from repro.core.roles import StreamingMean
+    from repro.core.fold import StreamingMean
     from repro.transport.wire import is_hub_partial, reduce_src
 
     def _update(seed: int) -> dict:
@@ -494,7 +494,7 @@ def check_install_reduce_fold(factory: Factory) -> None:
         for w in (dst, *srcs):
             be.join(ch, G, w)
         if reduced:
-            be.install_reduce(ch, G, dst, srcs, 1, None)
+            be.install_reduce(ch, G, dst, srcs, 1)
         # reverse sorted order: the fold must buffer out-of-order arrivals
         # and still consume them sorted-src
         for n, s in enumerate(reversed(srcs)):
@@ -542,9 +542,9 @@ def check_install_reduce_fold(factory: Factory) -> None:
     assert stats.get(f"hub_partials:{ch}") == 1, stats
 
     # reinstall is absolute-state: a half-folded round is discarded
-    be.install_reduce(ch, G, dst, srcs, 1, None)
+    be.install_reduce(ch, G, dst, srcs, 1)
     be.send(ch, G, srcs[0], dst, _update(seed=0))
-    be.install_reduce(ch, G, dst, srcs, 1, None)
+    be.install_reduce(ch, G, dst, srcs, 1)
     for i, s in enumerate(srcs):
         be.send(ch, G, s, dst, _update(seed=i))
     part2 = be.recv(ch, G, dst, reduce_src(0), timeout=5.0)
@@ -552,12 +552,12 @@ def check_install_reduce_fold(factory: Factory) -> None:
     assert np.asarray(part2["acc"]["w"]).tobytes() == np.asarray(ref_acc["w"]).tobytes()
 
     # a non-update frame on the reduced topic must not be swallowed
-    be.install_reduce(ch, G, dst, srcs, 1, None)
+    be.install_reduce(ch, G, dst, srcs, 1)
     be.send(ch, G, srcs[0], dst, {"hello": 1})
     assert be.recv(ch, G, dst, srcs[0], timeout=5.0) == {"hello": 1}
 
     # empty install uninstalls: next update is delivered per-frame
-    be.install_reduce(ch, G, dst, [], 0, None)
+    be.install_reduce(ch, G, dst, [], 0)
     be.send(ch, G, srcs[1], dst, _update(seed=1))
     back = be.recv(ch, G, dst, srcs[1], timeout=5.0)
     assert not is_hub_partial(back) and "weights" in back
@@ -570,7 +570,7 @@ def check_install_reduce_sharded(factory: Factory) -> None:
     produce byte-identical partials, and their shard-ordered combination
     matches the unreduced mean."""
     from repro.core.channels import reduce_blocks
-    from repro.core.roles import StreamingMean
+    from repro.core.fold import StreamingMean
     from repro.transport.wire import reduce_src
 
     def _update(seed: int) -> dict:
@@ -587,7 +587,7 @@ def check_install_reduce_sharded(factory: Factory) -> None:
         be = factory()
         for w in (dst, *srcs):
             be.join(ch, G, w)
-        be.install_reduce(ch, G, dst, srcs, 2, None)
+        be.install_reduce(ch, G, dst, srcs, 2)
         for i, s in enumerate(srcs):
             be.send(ch, G, s, dst, _update(seed=i))
         blocks = reduce_blocks(srcs, 2)

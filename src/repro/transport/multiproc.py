@@ -1287,7 +1287,6 @@ class MultiprocBackend:
         dst: str,
         srcs: Sequence[str],
         shards: int = 1,
-        fused: Optional[bool] = None,
     ) -> None:
         """Install/remove the hub-side reduce spec for ``dst``'s incast.
 
@@ -1298,7 +1297,7 @@ class MultiprocBackend:
         delivers one partial frame per shard. An absolute-state write, so
         its session-layer retry is exactly-once like every other op."""
         self._call(
-            "install_reduce", channel, group, dst, list(srcs), int(shards), fused
+            "install_reduce", channel, group, dst, list(srcs), int(shards)
         )
 
     # ----------------------------- clocks ------------------------------ #
@@ -1479,11 +1478,10 @@ class ShardRouter:
         dst: str,
         srcs: Sequence[str],
         shards: int = 1,
-        fused: Optional[bool] = None,
     ) -> None:
         # channel-scoped like send/recv: the (channel, group) topic — and so
         # its reduce state — lives on exactly one shard hub
-        self._be(group).install_reduce(channel, group, dst, srcs, shards, fused)
+        self._be(group).install_reduce(channel, group, dst, srcs, shards)
 
     # ----------------------------- clocks ------------------------------ #
     def now(self, worker: str) -> float:

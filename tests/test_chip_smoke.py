@@ -3,8 +3,8 @@
 The TPU check stays in ``main``; here the phase functions run directly on
 the CPU paths (jnp references for the kernels) to catch wrong paths,
 arguments and control flow before a chip run. The TPU-only facts (the
-fused path chosen by auto-dispatch, ``tpu_custom_call`` in the fold) are
-asserted by ``main`` on the chip.
+device fold chosen by the dispatch rule, ``tpu_custom_call`` in the
+quantizer) are asserted by ``main`` on the chip.
 """
 import os
 import subprocess
@@ -55,9 +55,10 @@ def test_phase_train_loss_falls(cfg):
     assert out["losses"][-1] < out["losses"][0]
 
 
-def test_phase_fold_is_bit_identical(updates):
-    out = cs.phase_fold(updates, fused=True)
-    assert out["fold_ran"]
+def test_phase_fold_is_bit_identical(updates, monkeypatch):
+    monkeypatch.setattr(cs.fold, "device_fold", lambda tree: True)
+    out = cs.phase_fold(updates)
+    assert out["fold_ran"] and out["no_fma"]
     assert out["divide_max_ulp"] == 0  # the CPU's division is IEEE
 
 

@@ -1,26 +1,25 @@
-"""Fused (stacked Pallas) aggregation vs the seed sequential accumulation.
+"""The device fold vs the seed sequential accumulation.
 
 The aggregator hot paths — sync/deadline ``weighted_mean`` and the FedBuff
-buffer flush — may run as one stacked ``repro.kernels.agg.aggregate_tree``
-call. The exact-mode kernel keeps the scale pass in a separate XLA
-computation from the add-only fold, so nothing FMA-contracts and the fused
-result must be **bit-identical** to the per-client ``tree_map`` loop it
-replaces — on every path (numpy loop, CPU jnp fold, interpret-mode Pallas
-kernel). These tests lock that equality, at the unit level and through
-seeded jobs.
+/FedAsync ``accumulate_stream`` — fold on the device through the jitted
+``scale``/``add`` pair of ``repro.core.fold`` where ``fold.device_fold``
+says so. The scale and the add are separate XLA computations, so nothing
+FMA-contracts and the device result must be **bit-identical** to the
+per-client ``tree_map`` loop. These tests lock that equality, at the unit
+level and through seeded jobs, forcing a path by patching
+``fold.device_fold``.
 """
 import jax
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
 
+from repro.core import fold
 from repro.core.expansion import JobSpec
 from repro.core.roles import weighted_mean
 from repro.core.runtime import RuntimePolicy, run_job
 from repro.core.tag import DatasetSpec
 from repro.core.topologies import classical_fl
 from repro.fl.strategies import get_strategy
-from repro.kernels.agg.ops import aggregate_tree
 from repro.transport.conformance import SeededSGDTrainer
 
 
@@ -39,6 +38,13 @@ def _updates(C, rng, shapes=((128, 130), (130,))):
         }
         out.append((tree, float(rng.integers(1, 40))))
     return out
+
+
+@pytest.fixture
+def force_fold(monkeypatch):
+    """``force_fold(on)``: every fold after it takes the device path (on)
+    or the host path; the jobs' threads see the patch too."""
+    return lambda on: monkeypatch.setattr(fold, "device_fold", lambda tree: on)
 
 
 def _seed_weighted_mean(updates):
@@ -78,12 +84,9 @@ class TestWeightedMeanBitEquality:
         assert _tree_bytes(auto) == _tree_bytes(forced)
 
     def test_signed_zero_columns_stay_bit_identical(self):
-        """An all-(-0.0) element must keep its sign through the fused fold
-        (a zeros-seeded accumulator would flip it to +0.0): the fold inits
-        from the first scaled row, on the CPU jnp path and the Pallas
-        kernel alike."""
-        from repro.kernels.agg.ops import aggregate_tree
-
+        """An all-(-0.0) element must keep its sign through the device fold
+        (a zeros-seeded accumulator would flip it to +0.0): the fold starts
+        from the first scaled update."""
         updates = [
             ({"w": np.array([-0.0, 5.0], np.float32)}, 1.0),
             ({"w": np.array([-0.0, 3.0], np.float32)}, 1.0),
@@ -91,52 +94,37 @@ class TestWeightedMeanBitEquality:
         fused, _ = weighted_mean(updates, fused=True)
         seed, _ = _seed_weighted_mean(updates)
         assert _tree_bytes(fused) == _tree_bytes(seed)
-        tree = {"w": np.stack([u[0]["w"] for u in updates])}
-        w = np.ones(2, np.float32)
-        out = aggregate_tree(tree, w, denom=2.0, exact=True, interpret=True)
-        assert _tree_bytes(out) == _tree_bytes(seed)
 
     def test_mismatched_treedefs_fall_back_to_sequential_error(self):
         """Clients whose trees differ in *structure* (not just shape) must
-        never be silently stacked under the first client's keys — the fused
-        path rejects them and the sequential path's error surfaces."""
+        never be silently folded under the first client's keys — the device
+        path rejects them with the host path's ValueError."""
         a = {"w1": np.ones((128, 130), np.float32)}
         b = {"w2": np.ones((128, 130), np.float32)}
         with pytest.raises(ValueError):
             weighted_mean([(a, 1.0), (b, 1.0)], fused=True)
 
     def test_ragged_clients_fall_back_gracefully(self):
-        """Structurally ineligible updates (ragged shapes) still aggregate —
-        via the sequential path — even when fused is forced."""
+        """Ragged updates are refused with the host path's ValueError even
+        when the device fold is forced."""
         a = {"w": np.ones((4, 4), np.float32)}
         b = {"w": np.ones((2, 2), np.float32)}
         with pytest.raises(ValueError):
-            # the seed loop itself cannot add ragged trees; eligibility
-            # filtering must reject them *before* stacking, so the error
-            # surface matches the sequential path
+            # the seed loop itself cannot add ragged trees; the device fold
+            # must refuse them before its jitted add, as numpy does
             weighted_mean([(a, 1.0), (b, 1.0)], fused=True)
-
-    def test_interpret_kernel_matches_cpu_jnp_fold(self):
-        """The actual Pallas fold kernel (interpret mode) and the CPU jnp
-        dispatch produce the same bits as the numpy seed loop."""
-        rng = np.random.default_rng(7)
-        updates = _updates(5, rng)
-        stacked = {
-            k: np.stack([u[0][k] for u in updates])
-            for k in updates[0][0]
-        }
-        w = np.asarray([n for _, n in updates], np.float32)
-        total = 0.0
-        for _, n in updates:
-            total += n
-        via_kernel = aggregate_tree(
-            stacked, w, denom=total, exact=True, interpret=True
-        )
-        seed, _ = _seed_weighted_mean(updates)
-        assert _tree_bytes(via_kernel) == _tree_bytes(seed)
 
 
 class TestFedBuffFlushBitEquality:
+    """``accumulate_stream`` on the device path reproduces the incremental
+    ``accumulate`` chain byte for byte, buffer after buffer."""
+
+    @staticmethod
+    def _streamed(strat, state, deltas, staleness):
+        for d, s in zip(deltas, staleness):
+            state = strat.accumulate_stream(state, d, s)
+        return state
+
     @pytest.mark.parametrize(
         "name,kwargs",
         [
@@ -145,7 +133,8 @@ class TestFedBuffFlushBitEquality:
             ("fedasync", dict(alpha=0.6, staleness_exp=0.5)),
         ],
     )
-    def test_batched_flush_reproduces_incremental(self, name, kwargs):
+    def test_batched_flush_reproduces_incremental(self, force_fold, name, kwargs):
+        force_fold(True)
         rng = np.random.default_rng(3)
         strat = get_strategy(name, **kwargs)
         params = {"w": rng.normal(size=(260, 64)).astype(np.float32)}
@@ -158,16 +147,17 @@ class TestFedBuffFlushBitEquality:
         inc = strat.init(params)
         for d, s in zip(deltas, stals):
             inc = strat.accumulate(inc, d, np.int32(s))
-        bat = strat.accumulate_batch(strat.init(params), deltas, stals, fused=True)
-        assert int(np.asarray(bat["count"])) == int(np.asarray(inc["count"]))
-        assert _tree_bytes(bat["acc"]) == _tree_bytes(inc["acc"])
+        dev = self._streamed(strat, strat.init(params), deltas, stals)
+        assert int(np.asarray(dev["count"])) == int(np.asarray(inc["count"]))
+        assert _tree_bytes(dev["acc"]) == _tree_bytes(inc["acc"])
         w_inc, _ = strat.apply(params, None, inc)
-        w_bat, _ = strat.apply(params, None, bat)
-        assert _tree_bytes(w_inc) == _tree_bytes(w_bat)
+        w_dev, _ = strat.apply(params, None, dev)
+        assert _tree_bytes(w_inc) == _tree_bytes(w_dev)
 
-    def test_batched_flush_signed_zero_matches_incremental(self):
+    def test_batched_flush_signed_zero_matches_incremental(self, force_fold):
         """Incremental FedBuff normalizes -0.0 via its leading ``0 + w*d``
-        add; the batched flush must reproduce that, not skip it."""
+        add; the device stream must reproduce that, not skip it."""
+        force_fold(True)
         strat = get_strategy("fedbuff", buffer_size=2, server_lr=1.0,
                              staleness_exp=0.5)
         params = {"w": np.zeros(2, np.float32)}
@@ -178,13 +168,12 @@ class TestFedBuffFlushBitEquality:
         inc = strat.init(params)
         for d in deltas:
             inc = strat.accumulate(inc, d, np.int32(0))
-        bat = strat.accumulate_batch(strat.init(params), deltas, [0, 0],
-                                     fused=True)
-        assert _tree_bytes(bat["acc"]) == _tree_bytes(inc["acc"])
+        dev = self._streamed(strat, strat.init(params), deltas, [0, 0])
+        assert _tree_bytes(dev["acc"]) == _tree_bytes(inc["acc"])
 
-    def test_nonzero_count_state_falls_back(self):
-        """A partially-filled state (count > 0) must keep sequential
-        semantics — the fold kernel only replaces full-buffer flushes."""
+    def test_nonzero_count_state_falls_back(self, force_fold):
+        """A partially-filled state (count > 0) streams on the device into
+        the bits of the sequential chain."""
         rng = np.random.default_rng(4)
         strat = get_strategy("fedbuff", buffer_size=3, server_lr=1.0,
                              staleness_exp=0.5)
@@ -194,17 +183,19 @@ class TestFedBuffFlushBitEquality:
             for _ in range(3)
         ]
         pre = strat.accumulate(strat.init(params), deltas[0], np.int32(1))
+        assert int(np.asarray(pre["count"])) == 1
         inc = pre
         for d in deltas[1:]:
             inc = strat.accumulate(inc, d, np.int32(0))
-        bat = strat.accumulate_batch(pre, deltas[1:], [0, 0], fused=True)
-        assert _tree_bytes(bat["acc"]) == _tree_bytes(inc["acc"])
+        force_fold(True)
+        dev = self._streamed(strat, pre, deltas[1:], [0, 0])
+        assert int(np.asarray(dev["count"])) == 3
+        assert _tree_bytes(dev["acc"]) == _tree_bytes(inc["acc"])
 
 
 class TestSeededJobBitEquality:
-    """The fused path plumbed through real seeded jobs: flipping the
-    ``fused_aggregation`` knob must never change a single byte of the
-    resulting global model."""
+    """The device fold through real seeded jobs: forcing the device or the
+    host path must never change a single byte of the global model."""
 
     def _job(self, rounds=3):
         rng = np.random.default_rng(7)
@@ -218,28 +209,24 @@ class TestSeededJobBitEquality:
             hyperparams={"rounds": rounds, "init_weights": w0},
         )
 
-    @staticmethod
-    def _with_fused(job, fused):
-        hp = dict(job.hyperparams)
-        hp["fused_aggregation"] = fused
-        return JobSpec(tag=job.tag, datasets=job.datasets, hyperparams=hp)
-
-    def test_sync_job_fused_vs_sequential(self):
+    def test_sync_job_fused_vs_sequential(self, force_fold):
         results = {}
         for fused in (True, False):
+            force_fold(fused)
             res = run_job(
-                self._with_fused(self._job(), fused), timeout=60,
+                self._job(), timeout=60,
                 program_overrides={"trainer": SeededSGDTrainer},
             )
             assert not res.errors
             results[fused] = res.global_weights()
         assert _tree_bytes(results[True]) == _tree_bytes(results[False])
 
-    def test_deadline_job_fused_vs_sequential(self):
+    def test_deadline_job_fused_vs_sequential(self, force_fold):
         results = {}
         for fused in (True, False):
+            force_fold(fused)
             res = run_job(
-                self._with_fused(self._job(), fused), timeout=60,
+                self._job(), timeout=60,
                 program_overrides={"trainer": SeededSGDTrainer},
                 policy=RuntimePolicy(mode="deadline", deadline=50.0, grace=2.0),
             )
@@ -247,25 +234,23 @@ class TestSeededJobBitEquality:
             results[fused] = res.global_weights()
         assert _tree_bytes(results[True]) == _tree_bytes(results[False])
 
-    def test_fedbuff_job_fused_vs_sequential(self):
+    def test_fedbuff_job_fused_vs_sequential(self, force_fold):
         """Single trainer + buffer_size=2: the only deterministic FedBuff
         arrival order (multi-trainer async order is wall-clock reactive),
-        so flipping the flush implementation must reproduce every byte."""
+        so switching the fold's path must reproduce every byte."""
         rng = np.random.default_rng(7)
         w0 = {
             "w": (0.01 * rng.normal(size=(32, 10))).astype(np.float32),
             "b": np.zeros((10,), np.float32),
         }
+        job = JobSpec(
+            tag=classical_fl(),
+            datasets=(DatasetSpec(name="d0"),),
+            hyperparams={"rounds": 4, "init_weights": w0},
+        )
         results = {}
         for fused in (True, False):
-            job = JobSpec(
-                tag=classical_fl(),
-                datasets=(DatasetSpec(name="d0"),),
-                hyperparams={
-                    "rounds": 4, "init_weights": w0,
-                    "fused_aggregation": fused,
-                },
-            )
+            force_fold(fused)
             res = run_job(
                 job, timeout=60,
                 program_overrides={"trainer": SeededSGDTrainer},
@@ -273,56 +258,7 @@ class TestSeededJobBitEquality:
             )
             assert not res.errors
             glob = res.program("global-aggregator-0")
-            # the buffered flush actually ran (buffer of 2, versions < uploads)
+            # the buffered apply actually ran (buffer of 2, versions < uploads)
             assert len(glob.staleness_log) >= 2
             results[fused] = res.global_weights()
         assert _tree_bytes(results[True]) == _tree_bytes(results[False])
-
-
-class TestAggregateTreeRaggedProperty:
-    """kernels/agg vs ref.py over ragged leaf shapes (satellite property
-    test): the tree wrapper must agree with a per-leaf reference whatever
-    the leaf shapes, and exact mode must agree bitwise with the sequential
-    fold."""
-
-    @settings(max_examples=12, deadline=None)
-    @given(
-        C=st.integers(1, 6),
-        shapes=st.lists(
-            st.tuples(st.integers(1, 9), st.integers(1, 11)),
-            min_size=1, max_size=4,
-        ),
-        seed=st.integers(0, 2**16),
-    )
-    def test_ragged_tree_matches_reference(self, C, shapes, seed):
-        rng = np.random.default_rng(seed)
-        tree = {
-            f"l{i}": rng.normal(size=(C,) + s).astype(np.float32)
-            for i, s in enumerate(shapes)
-        }
-        w = rng.uniform(0.5, 20.0, size=C).astype(np.float32)
-        total = float(np.float64(w.astype(np.float64).sum()))
-        out = aggregate_tree(tree, w, denom=total, exact=True)
-        # per-leaf sequential reference (the seed accumulation, leaf-wise)
-        for key, stacked in tree.items():
-            acc = None
-            for c in range(C):
-                scaled = stacked[c] * float(w[c])
-                acc = scaled if acc is None else np.add(acc, scaled)
-            ref = acc / total
-            got = np.asarray(out[key])
-            assert got.shape == stacked.shape[1:]
-            assert got.tobytes() == ref.tobytes()
-
-    @settings(max_examples=8, deadline=None)
-    @given(C=st.integers(1, 5), n=st.integers(3, 400), seed=st.integers(0, 999))
-    def test_default_mode_close_to_reference(self, C, n, seed):
-        from repro.kernels.agg.ops import aggregate_flat
-        from repro.kernels.agg.ref import reference_aggregate
-
-        rng = np.random.default_rng(seed)
-        d = rng.normal(size=(C, n)).astype(np.float32)
-        w = rng.uniform(0.1, 10.0, size=C).astype(np.float32)
-        out = np.asarray(aggregate_flat(d, w))
-        ref = np.asarray(reference_aggregate(d, w))
-        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)

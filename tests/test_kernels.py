@@ -5,8 +5,6 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.agg.ops import aggregate_flat, aggregate_tree
-from repro.kernels.agg.ref import reference_aggregate
 from repro.kernels.quant.ops import (
     compress_tree,
     decompress_tree,
@@ -92,95 +90,6 @@ class TestFusedAttention:
         np.testing.assert_array_equal(
             jax.jit(lambda q, k, v: attention(q, k, v, **kw))(q, k, v),
             jax.jit(lambda q, k, v: chunked_attention(q, k, v, **kw))(q, k, v))
-
-
-class TestAggKernel:
-    def test_against_reference(self):
-        d = jax.random.normal(jax.random.key(0), (5, 1000))
-        w = jnp.array([1.0, 2.0, 3.0, 4.0, 5.0])
-        np.testing.assert_allclose(
-            aggregate_flat(d, w), reference_aggregate(d, w), rtol=1e-6
-        )
-
-    def test_pallas_kernel_against_reference(self):
-        """The actual Pallas matmul kernel (interpret mode), not the CPU
-        jnp dispatch path."""
-        d = jax.random.normal(jax.random.key(0), (5, 1000))
-        w = jnp.array([1.0, 2.0, 3.0, 4.0, 5.0])
-        np.testing.assert_allclose(
-            aggregate_flat(d, w, interpret=True),
-            reference_aggregate(d, w),
-            rtol=1e-5, atol=1e-6,
-        )
-
-    def test_exact_fold_kernel_is_order_exact(self):
-        """The add-only fold kernel (interpret mode) reproduces sequential
-        IEEE accumulation bit-for-bit — the property the fused aggregator
-        path is built on."""
-        rng = np.random.default_rng(0)
-        d = rng.normal(size=(6, 1000)).astype(np.float32)
-        w = rng.uniform(1.0, 30.0, size=6).astype(np.float32)
-        total = 0.0
-        acc = None
-        for c in range(6):
-            scaled = d[c] * float(w[c])
-            total += float(w[c])
-            acc = scaled if acc is None else np.add(acc, scaled)
-        seed = acc / total
-        out = np.asarray(
-            aggregate_flat(d, w, denom=total, exact=True, interpret=True)
-        )
-        assert out.tobytes() == seed.tobytes()
-
-    def test_exact_fold_kernel_pads_to_whole_blocks(self):
-        """At 64 clients the fold's block is 16,384 wide, so a parameter
-        axis one block and 5 elements long runs two grid steps over a
-        zero-padded tail; the padding never reaches the result."""
-        from repro.kernels.agg.kernel import block_n_for, fold_scaled
-
-        C = 64
-        N = block_n_for(C) + 5
-        d = np.random.default_rng(1).normal(size=(C, N)).astype(np.float32)
-        acc = d[0]
-        for c in range(1, C):
-            acc = acc + d[c]
-        out = np.asarray(fold_scaled(jnp.asarray(d), interpret=True))
-        assert out.tobytes() == acc.tobytes()
-
-    @settings(max_examples=15, deadline=None)
-    @given(
-        C=st.integers(1, 8),
-        N=st.sampled_from([17, 256, 1000]),
-        wmax=st.floats(0.1, 100),
-    )
-    def test_weighted_mean_property(self, C, N, wmax):
-        ks = jax.random.split(jax.random.key(C * N), 2)
-        d = jax.random.normal(ks[0], (C, N))
-        w = jax.random.uniform(ks[1], (C,), minval=0.01, maxval=wmax)
-        out = np.asarray(aggregate_flat(d, w))
-        ref = np.asarray(reference_aggregate(d, w))
-        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
-        # the mean lies within the per-element min/max envelope
-        assert (out <= np.max(np.asarray(d), 0) + 1e-5).all()
-        assert (out >= np.min(np.asarray(d), 0) - 1e-5).all()
-
-    @pytest.mark.parametrize("C", [1, 3, 8, 9, 64, 200])
-    def test_block_width_fits_scoped_vmem(self, C):
-        """The fold's block shrinks with the client count so that the
-        double-buffered f32 (C, block) input block stays within budget."""
-        from repro.kernels.agg.kernel import _IN_BLOCK_BUDGET, block_n_for
-
-        block = block_n_for(C)
-        rows = -(-C // 8) * 8
-        assert block % 128 == 0 and block <= 65_536
-        assert 2 * rows * block * 4 <= _IN_BLOCK_BUDGET
-        # and it is the widest power of two that does
-        assert block == 65_536 or 2 * rows * 2 * block * 4 > _IN_BLOCK_BUDGET
-
-    def test_tree_roundtrip_shapes(self):
-        tree = {"a": jnp.ones((4, 3, 5)), "b": jnp.zeros((4, 7))}
-        out = aggregate_tree(tree, jnp.ones(4))
-        assert out["a"].shape == (3, 5) and out["b"].shape == (7,)
 
 
 class TestQuantKernel:

@@ -1,15 +1,15 @@
 """The program's host spans and the streaming fold's copy counters.
 
-A classical-FL job (3 trainers, 2 rounds, small float32 updates, the fused
+A classical-FL job (3 trainers, 2 rounds, small float32 updates, the device
 fold forced on) runs under the JAX profiler on the CPU; its trace must hold
 every span of ``repro.core.spans`` under its exact name, and the fold's
-per-round byte counters must match what the fused path copies.
+per-round byte counters must match what the device path copies.
 """
 import jax
 import numpy as np
 import pytest
 
-from repro.core import spans
+from repro.core import fold, spans
 from repro.core.expansion import JobSpec
 from repro.core.runtime import run_job
 from repro.core.tag import DatasetSpec
@@ -61,9 +61,15 @@ def _traced(logdir, job):
     return res, events
 
 
+def _force_fold(mp, on):
+    mp.setattr(fold, "device_fold", lambda tree: on)
+
+
 @pytest.fixture(scope="module")
 def fused(tmp_path_factory):
-    return _traced(tmp_path_factory.mktemp("fused"), _job(fused_aggregation=True))
+    with pytest.MonkeyPatch.context() as mp:
+        _force_fold(mp, True)
+        return _traced(tmp_path_factory.mktemp("fused"), _job())
 
 
 @pytest.fixture(scope="module")
@@ -124,15 +130,16 @@ def test_no_program_span_takes_a_benchmark_name(fused, reduced):
         assert not {n for n, _, _ in events} & BENCHMARK_SPANS
 
 
-@pytest.mark.parametrize("fused_aggregation", [True, False])
-def test_copy_counters_count_what_the_fold_moves(fused_aggregation):
-    res = run_job(_job(fused_aggregation=fused_aggregation), timeout=60)
+@pytest.mark.parametrize("device", [True, False])
+def test_copy_counters_count_what_the_fold_moves(device, monkeypatch):
+    _force_fold(monkeypatch, device)
+    res = run_job(_job(), timeout=60)
     assert not res.errors, res.errors
     rounds = _agg_metrics(res)
     assert len(rounds) == ROUNDS
     for m in rounds:
         assert m["agg_folds"] == TRAINERS
-        if fused_aggregation:
+        if device:
             # each update in; the accumulator out once, at finalize
             assert m["h2d_bytes"] == TRAINERS * N
             assert m["d2h_bytes"] == N
@@ -141,9 +148,10 @@ def test_copy_counters_count_what_the_fold_moves(fused_aggregation):
             assert m["h2d_bytes"] == m["d2h_bytes"] == 0
 
 
-def test_tracing_leaves_the_weights_bit_identical(fused):
+def test_tracing_leaves_the_weights_bit_identical(fused, monkeypatch):
     traced = fused[0].global_weights()
-    plain = run_job(_job(fused_aggregation=True), timeout=60)
+    _force_fold(monkeypatch, True)
+    plain = run_job(_job(), timeout=60)
     assert not plain.errors, plain.errors
     for k in W0:
         assert np.asarray(traced[k]).tobytes() == np.asarray(plain.global_weights()[k]).tobytes()

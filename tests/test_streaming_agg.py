@@ -1,4 +1,4 @@
-"""Streaming aggregation: O(1) server memory, bit-identical to batched.
+"""Streaming aggregation: O(1) server memory, bit-identical to the sequential fold.
 
 The aggregator roles fold arriving updates one at a time through
 ``StreamingMean`` / ``ServerStrategy.accumulate_stream`` instead of
@@ -6,10 +6,10 @@ buffering every client tree and folding at round close. These tests pin the
 two invariants the docs advertise (docs/ARCHITECTURE.md):
 
 * **bit-identity** — for the same fold order, the streaming fold executes
-  the exact IEEE op sequence of the batched path (scale each update, add
-  into the accumulator, divide once by the total), so results match the
-  buffered ``weighted_mean`` / ``accumulate_batch`` byte for byte, on
-  ragged pytrees included;
+  the exact IEEE op sequence of the sequential fold (scale each update, add
+  into the accumulator, divide once by the total), so results match
+  ``weighted_mean`` and the incremental ``accumulate`` chain byte for byte,
+  on ragged pytrees included;
 * **O(1) server memory** — the peak number of client update trees held at
   once is 1 regardless of client count (``peak_buffered``).
 """
@@ -17,7 +17,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import roles
+from repro.core import fold
 from repro.core.expansion import JobSpec
 from repro.core.roles import StreamingMean, weighted_mean
 from repro.core.runtime import run_job
@@ -68,9 +68,9 @@ class TestStreamingMeanMatchesBatched:
         assert acc.count == n_clients
 
     def test_fused_matches_sequential_bitwise(self):
-        """The jitted per-update scale/add pair (the kernel's exact-mode
-        split, which forbids FMA contraction) must be byte-identical to the
-        eager numpy fold in the same order."""
+        """The jitted per-update scale/add pair (two jit entries, so no FMA
+        contraction) must be byte-identical to the eager numpy fold in the
+        same order."""
         rng = np.random.default_rng(9)
         updates = [(_ragged_tree(rng), float(i + 1)) for i in range(6)]
         seq = StreamingMean(fused=False)
@@ -170,7 +170,7 @@ class TestFusedAccumulatorStaysOnDevice:
 
     @pytest.mark.parametrize("n_clients", [1, 2, 8])
     def test_without_room_on_the_device_each_sum_goes_back(self, n_clients, monkeypatch):
-        monkeypatch.setattr(roles, "_device_free_bytes", lambda: 0)
+        monkeypatch.setattr(fold, "_device_free_bytes", lambda: 0)
         updates = _updates(n_clients, 71 + n_clients)
         nbytes = _nbytes(updates[0][0])
         fused = StreamingMean(fused=True)
@@ -193,7 +193,7 @@ class TestFusedAccumulatorStaysOnDevice:
         updates = _updates(2, 81)
         sizes = [x.nbytes for x in jax.tree_util.tree_leaves(updates[0][0])]
         free = None if spare is None else 2 * sum(sizes) + 2 * max(sizes) + spare
-        monkeypatch.setattr(roles, "_device_free_bytes", lambda: free)
+        monkeypatch.setattr(fold, "_device_free_bytes", lambda: free)
         fused = _folded(updates, fused=True)
         leaves = jax.tree_util.tree_leaves(fused._acc)
         assert all(isinstance(x, jax.Array) == resident for x in leaves)
@@ -201,19 +201,21 @@ class TestFusedAccumulatorStaysOnDevice:
 
 class TestStrategyStreamMatchesBatch:
     @pytest.mark.parametrize("strategy", [FedBuff(buffer_size=8), FedAsync()])
-    def test_accumulate_stream_equals_accumulate_batch(self, strategy):
+    def test_accumulate_stream_equals_accumulate_chain(self, strategy, monkeypatch):
+        """On the device path, ``accumulate_stream`` gives the bytes of the
+        incremental ``accumulate`` chain."""
+        monkeypatch.setattr(fold, "device_fold", lambda tree: True)
         rng = np.random.default_rng(23)
         deltas = [_ragged_tree(rng, scale=0.1) for _ in range(5)]
         staleness = [0, 2, 1, 4, 0]
         params = _ragged_tree(np.random.default_rng(0))
-        batch_state = strategy.accumulate_batch(
-            strategy.init(params), deltas, staleness
-        )
+        chain_state = strategy.init(params)
         stream_state = strategy.init(params)
         for delta, s in zip(deltas, staleness):
+            chain_state = strategy.accumulate(chain_state, delta, np.int32(s))
             stream_state = strategy.accumulate_stream(stream_state, delta, s)
-        assert int(batch_state["count"]) == int(stream_state["count"]) == 5
-        assert _leaves_bytes(batch_state["acc"]) == _leaves_bytes(
+        assert int(chain_state["count"]) == int(stream_state["count"]) == 5
+        assert _leaves_bytes(chain_state["acc"]) == _leaves_bytes(
             stream_state["acc"]
         )
 

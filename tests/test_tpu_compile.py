@@ -1,4 +1,4 @@
-"""Ahead-of-time compiles of the main path's Pallas kernels for a TPU v5e.
+"""Ahead-of-time compiles of the main path's kernels for a TPU v5e.
 
 The TPU compiler is installed without the chip. Each test lowers a kernel
 at a real size for one chip of a described ``v5e:2x2`` topology and
@@ -20,11 +20,11 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.agg.kernel import fold_scaled, weighted_aggregate
+from repro.core import fold
 from repro.kernels.quant.kernel import dequantize_blocks, quantize_blocks
 
-# parameters in one qwen2.5-3b decoder layer
-LAYER = 77_076_992
+# the largest leaf of one qwen2.5-3b decoder layer: its MLP projection
+FOLD_LEAF = (2048, 11008)
 QUANT_ELEMS = 64 * 2**20
 # the fused attention kernels: forward with its residuals, dq and dkv
 SPLASH_KERNELS = ("splash_mha_fwd", "splash_mha_dq", "splash_mha_dkv")
@@ -63,27 +63,16 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize(
-    "clients,n",
-    # 64 clients of a whole layer in f32 (19.7 GB) exceed one chip's HBM;
-    # an eighth of a layer keeps the stack at the 8-client size
-    [(8, LAYER), (64, LAYER // 8)],
-)
-def test_fold_scaled_compiles(one_chip, clients, n):
-    compiled = _compile(
-        lambda s: fold_scaled(s), _spec(one_chip, (clients, n))
-    )
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_weighted_aggregate_compiles(one_chip):
-    compiled = _compile(
-        lambda d, w, den: weighted_aggregate(d, w, den),
-        _spec(one_chip, (8, LAYER)),
-        _spec(one_chip, (8,)),
-        _spec(one_chip, (1,)),
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+@pytest.mark.parametrize("op, absent", [("scale", "add"), ("add", "multiply")])
+def test_fold_pair_compiles_without_fma(one_chip, op, absent):
+    """The fold's ``scale`` and ``add`` at the tag cell's largest leaf: each
+    its own program, so the compiler can contract no multiply-add."""
+    leaf = _spec(one_chip, FOLD_LEAF)
+    other = _spec(one_chip, ()) if op == "scale" else leaf
+    text = getattr(fold, op).lower(leaf, other).compile().as_text()
+    ops = set(re.findall(r"= \S+ (\w+)\(", text))
+    assert {"scale": "multiply", "add": "add"}[op] in ops
+    assert absent not in ops and "fma" not in ops, sorted(ops)
 
 
 def test_quantize_blocks_compiles(one_chip):
