@@ -12,11 +12,20 @@ nothing.
 
 The picks that land on held experts are sorted by expert and run as one
 grouped matrix product per weight (``jax.lax.ragged_dot``), which computes
-only the rows its groups fill. The sorted buffer has a row for every pick
-that can land here, N · min(k, held), so no pick is dropped at any
-imbalance. Each call counts the picks it computed (``moe_held_picks``),
-those it could not (``moe_dropped``, 0 by construction) and the largest
-held expert's picks (``moe_load_max``).
+only the rows its groups fill. Dispatch gathers each sorted row's token,
+and combine adds each row's weighted output back into its token in f32.
+
+The buffer rule: the sorted buffer's row count is a rung of a ladder fixed
+by the shapes (``buffer_rungs``): N · min(k, held), a row for every pick
+that can land here, then halvings of it down to twice the even fill
+N · k · held / E. Each call runs dispatch, experts and combine over the
+smallest rung that holds its held picks, chosen on the device
+(``jax.lax.switch``), so no pick is dropped at any imbalance and evenly
+routed picks do not pay for the worst case. A layer that holds every
+expert has the one rung. Each call counts the picks it computed
+(``moe_held_picks``), those it could not (``moe_dropped``, 0 by
+construction), the largest held expert's picks (``moe_load_max``) and the
+rows of the rung it ran (``moe_rows``).
 
 Router aux loss is the standard load-balancing loss (Shazeer/Switch) over
 every expert: ``E * sum_e f_e * P_e`` with f the share of the layer's picks
@@ -24,6 +33,8 @@ and P the mean router probability.
 """
 from __future__ import annotations
 
+import functools
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -34,7 +45,27 @@ from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, mlp_apply, mlp_init
 
 Tree = Dict[str, jax.Array]
-COUNTERS = ("moe_held_picks", "moe_dropped", "moe_load_max")
+COUNTERS = ("moe_held_picks", "moe_dropped", "moe_load_max", "moe_rows")
+# the grouped product's row tile: every rung of the buffer is a multiple of it
+ROW_TILE = 128
+
+
+def buffer_rungs(N: int, k: int, E: int, H: int) -> Tuple[int, ...]:
+    """The sorted buffer's row counts, largest first. The first is
+    N · min(k, H), a row for every pick that can land on a held expert; the
+    others halve it down to the last that still holds twice the even fill
+    N · k · H / E, each rounded up to a whole ``ROW_TILE``. A layer that
+    holds every expert, or whose even fill passes a quarter of the first,
+    has that one alone."""
+    rows = N * min(k, H)
+    out = [rows]
+    j = 1
+    while rows * E >= 2 ** (j + 1) * N * k * H:  # rows / 2^j >= 2 · even fill
+        rung = -(-rows // (2**j * ROW_TILE)) * ROW_TILE
+        if rung < out[-1]:
+            out.append(rung)
+        j += 1
+    return tuple(out)
 
 
 def moe_init(rng, cfg: ModelConfig, dtype) -> Tree:
@@ -87,65 +118,120 @@ def _experts(xs, gate, up, down, sizes):
     return jax.lax.ragged_dot(h, down, sizes)
 
 
-def _gather_sum(r, slot, held, w):
-    """(N, d): sum over the held picks j of ``w[n, j] * r[slot[n, j]]``,
-    added in f32; an absent pick's row is never read into the sum."""
-    N, k = slot.shape
-    picked = r[slot.reshape(-1)].reshape(N, k, -1).astype(jnp.float32)
-    return jnp.sum(jnp.where(held[..., None], picked * w[..., None], 0.0),
-                   axis=1).astype(r.dtype)
+def _rows(order, sizes, C, k):
+    """The first ``C`` sorted rows: each row's pick, its token, and whether
+    it holds a held pick (rows past the groups' total hold none)."""
+    pick = order[:C]
+    return pick, pick // k, jnp.arange(C) < jnp.sum(sizes)
 
 
-# Dispatch and combine move tokens to sorted rows and back. As plain
-# indexing, each one's backward would be a scatter; written as each other's
-# transpose, both directions are gathers and a token's picks sum in f32.
-@jax.custom_vjp
-def _dispatch(x, pick, slot, held):
-    """x: (N, d) -> (rows, d): row r holds the token of pick ``pick[r]``."""
-    return x[pick // slot.shape[1]]
+def _segment_sum(rows, tok, live, N, chunk, w=None):
+    """(N, d) float32: each live row (times its weight ``w``) added into its
+    token, ``chunk`` rows at a time, so no more than ``chunk`` rows are held
+    in float32 at once. A row that holds no pick is sent past the table, so
+    its value is never read."""
+    tok = jnp.where(live, tok, N)
+    C, d = rows.shape
+
+    def add(acc, r, t, w):
+        r = r.astype(jnp.float32)
+        if w is not None:
+            r = r * w[:, None]
+        return acc.at[t].add(r, mode="drop")
+
+    acc = jnp.zeros((N, d), jnp.float32)
+    if C == chunk:
+        return add(acc, rows, tok, w)
+
+    def body(i, acc):
+        part = lambda a: jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk)  # noqa: E731
+        return add(acc, part(rows), part(tok), None if w is None else part(w))
+
+    return jax.lax.fori_loop(0, C // chunk, body, acc)
 
 
-def _dispatch_fwd(x, pick, slot, held):
-    return _dispatch(x, pick, slot, held), (slot, held)
+def _forward(C, chunk, xf, w, gate, up, down, order, sizes):
+    """y (N, d) over a buffer of ``C`` rows: each token's held picks'
+    outputs weighted and summed in f32."""
+    N, k = w.shape
+    with scope(MOE_DISPATCH):
+        pick, tok, live = _rows(order, sizes, C, k)
+        xs = xf[tok]
+    with scope(MOE_EXPERTS):
+        out = _experts(xs, gate, up, down, sizes)
+    with scope(MOE_COMBINE):
+        y = _segment_sum(out, tok, live, N, chunk, w.reshape(-1)[pick])
+    return y.astype(xf.dtype)
 
 
-def _dispatch_bwd(res, g):
-    slot, held = res
-    return _gather_sum(g, slot, held, jnp.ones(held.shape, jnp.float32)), None, None, None
+def _backward(C, chunk, xf, w, gate, up, down, order, sizes, g):
+    """The transpose of ``_forward`` at ``g`` (N, d), recomputing the
+    experts over the same ``C`` rows: the combine's segment sum turns into a
+    gather of each row's token, the dispatch's gather into a segment sum."""
+    N, k = w.shape
+    with scope(MOE_DISPATCH):
+        pick, tok, live = _rows(order, sizes, C, k)
+        xs = xf[tok]
+    with scope(MOE_EXPERTS):
+        out, experts_vjp = jax.vjp(
+            lambda *a: _experts(*a, sizes), xs, gate, up, down)
+    with scope(MOE_COMBINE):
+        w_row = w.reshape(-1)[pick]
+        g_row = g[tok]
+        d_w_row = jnp.sum(out.astype(jnp.float32) * g_row.astype(jnp.float32), axis=-1)
+        d_w = jnp.zeros((N * k,), jnp.float32).at[pick].set(
+            jnp.where(live, d_w_row, 0.0), unique_indices=True)
+        d_out = jnp.where(live[:, None], g_row.astype(jnp.float32) * w_row[:, None],
+                          0.0).astype(out.dtype)
+    with scope(MOE_EXPERTS):
+        d_xs, d_gate, d_up, d_down = experts_vjp(d_out)
+    with scope(MOE_DISPATCH):
+        d_x = _segment_sum(d_xs, tok, live, N, chunk).astype(xf.dtype)
+    return d_x, d_w.reshape(N, k), d_gate, d_up, d_down
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _rung(ladder, total):
+    """The index in ``ladder`` (largest first) of the smallest rung that
+    holds ``total`` rows."""
+    return jnp.sum(jnp.asarray(ladder) >= total) - 1
 
 
-@jax.custom_vjp
-def _combine(out, w, held, pick, slot):
-    """out: (rows, d), w: (N, k) -> (N, d): each token's held picks weighted."""
-    return _gather_sum(out, slot, held, w)
+def _on_rung(ladder, rung, fn, *args):
+    """``fn(C, chunk, *args)`` with C the rung of index ``rung``."""
+    chunk = math.gcd(*ladder)  # the smallest rung, where it divides the others
+    if len(ladder) == 1:
+        return fn(ladder[0], chunk, *args)
+    return jax.lax.switch(rung, [functools.partial(fn, C, chunk) for C in ladder], *args)
 
 
-def _combine_fwd(out, w, held, pick, slot):
-    return _gather_sum(out, slot, held, w), (out, w, held, pick, slot)
+# The rung is switched on inside the forward and again inside the backward,
+# so nothing of a rung's size crosses from one to the other: the backward
+# recomputes the experts over its rows (the step rematerialises the layer
+# anyway), and the residuals are the inputs.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(ladder, rung, xf, w, gate, up, down, order, sizes):
+    """xf: (N, d), w: (N, k) -> (N, d): each token's held picks weighted."""
+    return _on_rung(ladder, rung, _forward, xf, w, gate, up, down, order, sizes)
 
 
-def _combine_bwd(res, g):
-    out, w, held, pick, slot = res
-    N, k = slot.shape
-    held_row = held.reshape(-1)[pick]  # rows of absent picks get nothing
-    w_row = jnp.where(held_row, w.reshape(-1)[pick], 0.0)
-    d_out = (g[pick // k].astype(jnp.float32) * w_row[:, None]).astype(out.dtype)
-    picked = out[slot.reshape(-1)].reshape(N, k, -1).astype(jnp.float32)
-    d_w = jnp.where(held, jnp.sum(picked * g.astype(jnp.float32)[:, None, :], axis=-1), 0.0)
-    return d_out, d_w, None, None, None
+def _held_experts_fwd(ladder, rung, *args):
+    return _held_experts(ladder, rung, *args), (rung,) + args
 
 
-_combine.defvjp(_combine_fwd, _combine_bwd)
+def _held_experts_bwd(ladder, res, g):
+    rung, *args = res
+    return (None,) + _on_rung(ladder, rung, _backward, *args, g) + (None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def moe_apply(p: Tree, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
     """x: (B, S, d) -> (y: (B, S, d), stats: ``zero_stats()``'s keys)."""
     B, S, d = x.shape
     N, E, k, H = B * S, cfg.num_experts, cfg.experts_per_token, cfg.held_experts
-    M, rows = N * k, N * min(k, H)
+    M = N * k
+    ladder = buffer_rungs(N, k, E, H)
     xf = x.reshape(N, d)
 
     with scope(MOE_ROUTE):
@@ -161,27 +247,19 @@ def moe_apply(p: Tree, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, Dict]
         group = jnp.where(held, local, H).reshape(-1)  # absent picks sort last
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((H,), jnp.int32).at[group].add(1, mode="drop")
-        pick = order[:rows]  # the pick of each sorted row
-        # each pick's row; an absent pick past the buffer points at the last
-        # row, and ``held`` masks it out
-        slot = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
-        slot = jnp.minimum(slot, rows - 1).reshape(N, k)
-        xs = _dispatch(xf, pick, slot, held)
+        held_picks = jnp.sum(sizes)
+        rung = _rung(ladder, held_picks)
+        rows = jnp.asarray(ladder)[rung]
 
-    with scope(MOE_EXPERTS):
-        out = _experts(xs, p["gate"], p["up"], p["down"], sizes)  # (rows, d)
-
-    with scope(MOE_COMBINE):
-        y = _combine(out, top_w, held, pick, slot)
-
+    y = _held_experts(ladder, rung, xf, top_w, p["gate"], p["up"], p["down"], order, sizes)
     y = y.reshape(B, S, d)
     if cfg.shared_expert:
         y = y + mlp_apply(p["shared"], x, cfg.activation)
-    held_picks = jnp.sum(sizes)
     stats = {
         "router_aux": aux,
         "moe_held_picks": held_picks,
         "moe_dropped": jnp.maximum(held_picks - rows, 0),
         "moe_load_max": jnp.max(sizes),
+        "moe_rows": rows,
     }
     return y, stats

@@ -23,7 +23,7 @@ from repro.models import reference_qwen3_moe as ref
 from repro.models import transformer
 from repro.models.api import build_model
 from repro.models.blocks import block_apply
-from repro.models.moe import moe_apply, moe_init
+from repro.models.moe import buffer_rungs, moe_apply, moe_init
 
 LOSS_RTOL = 1e-5  # float32 both sides: summation order only
 GRAD_RTOL = 1e-4  # relative L2 per leaf, float32 both sides
@@ -203,6 +203,7 @@ def test_no_pick_is_dropped_under_total_imbalance():
     assert int(stats["moe_dropped"]) == 0
     assert int(stats["moe_held_picks"]) == n * cfg.experts_per_token
     assert int(stats["moe_load_max"]) == n
+    assert int(stats["moe_rows"]) == n * min(cfg.experts_per_token, cfg.held_experts)
     lp = {"router": p["router"]["w"], "gate": p["gate"], "up": p["up"], "down": p["down"]}
     with jax.default_matmul_precision("highest"):
         top_e, top_w, _ = ref.route(lp, x.reshape(n, -1), cfg)
@@ -253,10 +254,164 @@ def test_rows_past_the_groups_are_never_read(monkeypatch):
     bundle = build_model(cfg)
     params = bundle.init(jax.random.key(0))
     tokens = _tokens(cfg)
-    (loss, _), grads = jax.value_and_grad(bundle.loss_fn, has_aux=True)(
+    (loss, counters), grads = jax.value_and_grad(bundle.loss_fn, has_aux=True)(
         params, {"tokens": tokens}, None)
+    # both layers ran the smaller of the two rungs, (256, 128)
+    assert buffer_rungs(B * S, 4, 16, 4) == (256, 128)
+    assert int(counters["moe_rows"]) == cfg.num_layers * 128
     r_loss, _, r_grads = ref.loss_and_grads(ref.params_from_model(params, cfg), tokens, cfg)
     np.testing.assert_allclose(loss, r_loss, rtol=LOSS_RTOL)
     got = ref.params_from_model(grads, cfg)
     for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(r_grads)):
         assert bool(jnp.all(jnp.isfinite(g))) and _rel(g, w) < GRAD_RTOL
+
+
+# --------------------------------------------------------------------------- #
+# the sorted buffer's ladder of rungs
+# --------------------------------------------------------------------------- #
+# 512 tokens, top 4 of 64 experts, the second share of 4 held: a row for
+# each of the 2,048 picks that can land here, an even fill of 128
+LADDER = dict(num_experts=64, experts_per_token=4, experts_held=4, expert_shard=1)
+LADDER_TOKENS = (2, 256)
+RUNGS = (2048, 1024, 512, 256)
+
+
+@pytest.mark.parametrize("shape, rungs", [
+    ((16384, 8, 128, 8), (131072, 65536, 32768, 16384)),  # the step cell
+    ((512, 4, 64, 4), RUNGS),
+    ((64, 4, 16, 4), (256, 128)),
+    ((64, 4, 16, 16), (256,)),  # every expert held
+    ((16384, 1, 128, 128), (16384,)),
+    ((8, 8, 128, 8), (64,)),  # no halving is a whole tile smaller
+    ((512, 4, 8, 4), (2048,)),  # the even fill passes a quarter of the rows
+    ((96, 4, 64, 4), (384, 256, 128)),  # rounded up to whole tiles
+])
+def test_the_ladder_follows_the_shapes(shape, rungs):
+    """(N, k, E, held) -> rungs: N·min(k, held), then halvings down to the
+    last that holds twice the even fill N·k·held/E, in whole row tiles."""
+    assert buffer_rungs(*shape) == rungs
+
+
+def _routed(total, seed=0):
+    """A layer of the ``LADDER`` shape, tokens and a router under which
+    exactly ``total`` picks land on the held experts (4-7): a token of class
+    j (its one-hot direction, plus a little noise) picks j held experts and
+    4 - j absent ones, and the classes are spread over the tokens."""
+    cfg = small(**LADDER)
+    N, d, k = LADDER_TOKENS[0] * LADDER_TOKENS[1], cfg.d_model, cfg.experts_per_token
+    p = moe_init(jax.random.key(seed), cfg, jnp.float32)
+    w = np.zeros((d, cfg.num_experts), np.float32)
+    for j in range(k + 1):
+        chosen = [4 + i for i in range(j)] + [8 + i for i in range(k - j)]
+        w[j, chosen] = 8.0 - np.arange(k)
+    p["router"] = {"w": jnp.asarray(w)}
+    full, rest = divmod(total, k)
+    cls = np.array([k] * full + [rest] * (rest > 0) + [0] * (N - full - (rest > 0)))
+    cls = np.random.default_rng(seed).permutation(cls)
+    noise = jax.random.normal(jax.random.key(seed + 1), (N, d))
+    x = (jax.nn.one_hot(cls, d) + 0.01 * noise).reshape(*LADDER_TOKENS, d)
+    return cfg, p, x
+
+
+def _layer_and_reference(cfg, p, x):
+    """The layer's and the reference's sum(y · c) with its gradients in x,
+    the router and the three expert weights, and the layer's counters."""
+    n, d = x.shape[0] * x.shape[1], x.shape[-1]
+    c = jax.random.normal(jax.random.key(9), (n, d))
+    names = ("router", "gate", "up", "down")
+    leaves = lambda p: (p["router"]["w"], p["gate"], p["up"], p["down"])  # noqa: E731
+
+    def layer(x, r, gate, up, down):
+        q = dict(p, router={"w": r}, gate=gate, up=up, down=down)
+        y, stats = moe_apply(q, x, cfg)
+        return jnp.sum(y.reshape(n, d) * c), stats
+
+    def reference(x, r, gate, up, down):
+        lp = dict(router=r, gate=gate, up=up, down=down)
+        with jax.default_matmul_precision("highest"):
+            top_e, top_w, _ = ref.route(lp, x.reshape(n, d), cfg)
+            y, _ = ref.experts(lp, x.reshape(n, d), top_e, top_w, cfg)
+        return jnp.sum(y * c)
+
+    (got, stats), g_got = jax.value_and_grad(layer, argnums=range(5), has_aux=True)(
+        x, *leaves(p))
+    want, g_want = jax.value_and_grad(reference, argnums=range(5))(x, *leaves(p))
+    return got, want, dict(zip(("x",) + names, g_got)), dict(zip(("x",) + names, g_want)), stats
+
+
+@pytest.mark.parametrize("total, rung", [
+    (0, 256), (256, 256), (257, 512), (512, 512), (513, 1024), (1024, 1024),
+    (1025, 2048), (2048, 2048)])
+def test_each_rung_matches_the_reference_at_its_boundaries(total, rung):
+    """Held-pick totals at and just past each rung's rows run on the rung
+    that holds them, and the layer's output and gradients equal the
+    reference's."""
+    assert buffer_rungs(512, 4, 64, 4) == RUNGS
+    got, want, g_got, g_want, stats = _layer_and_reference(*_routed(total))
+    assert int(stats["moe_held_picks"]) == total
+    assert int(stats["moe_rows"]) == rung
+    assert int(stats["moe_dropped"]) == 0
+    np.testing.assert_allclose(got, want, rtol=LOSS_RTOL, atol=1e-6)
+    for name, w in g_want.items():
+        if total:
+            assert float(jnp.linalg.norm(w)) > 0, name
+            assert _rel(g_got[name], w) < GRAD_RTOL, name
+        else:  # no pick reached a held expert: nothing gets a gradient
+            assert float(jnp.max(jnp.abs(g_got[name]))) == 0.0, name
+
+
+@pytest.mark.parametrize("total", [200, 700, 2048])
+def test_rows_past_the_groups_are_never_read_on_any_rung(monkeypatch, total):
+    """NaN past the groups' total on the rungs of 256, 1,024 and 2,048
+    rows: the layer's output and gradients still equal the reference's."""
+    real = jax.lax.ragged_dot
+
+    def garbage_past_groups(lhs, rhs, group_sizes, **kw):
+        out = real(lhs, rhs, group_sizes, **kw)
+        live = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+        return jnp.where(live[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", garbage_past_groups)
+    got, want, g_got, g_want, stats = _layer_and_reference(*_routed(total, seed=3))
+    assert int(stats["moe_rows"]) == {200: 256, 700: 1024, 2048: 2048}[total]
+    np.testing.assert_allclose(got, want, rtol=LOSS_RTOL, atol=1e-6)
+    for name, w in g_want.items():
+        assert bool(jnp.all(jnp.isfinite(g_got[name]))) and _rel(g_got[name], w) < GRAD_RTOL
+
+
+def _wide_rows(jaxpr, branch, rows, widths):
+    """Shapes of the equations in ``jaxpr`` and its inner jaxprs with
+    ``rows`` rows (all but the last dimension) at one of ``widths``; of a
+    ``cond``, only branch ``branch`` is read."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if shape and shape[-1] in widths and int(np.prod(shape[:-1])) == rows:
+                found.append((eqn.primitive.name, shape))
+        if eqn.primitive.name == "cond":
+            inner = [eqn.params["branches"][branch]]
+        else:
+            inner = [q for v in eqn.params.values()
+                     for q in (v if isinstance(v, (tuple, list)) else [v])
+                     if isinstance(q, (jax.extend.core.ClosedJaxpr, jax.extend.core.Jaxpr))]
+        for q in inner:
+            found += _wide_rows(getattr(q, "jaxpr", q), branch, rows, widths)
+    return found
+
+
+def test_the_smallest_rung_holds_nothing_of_the_full_buffer():
+    """The layer's forward and backward on the smallest rung hold no array
+    of N·min(k, held) rows at the model's or the experts' width; on the
+    full rung they do."""
+    cfg, p, x = _routed(100)
+
+    def loss(p, x):
+        y, _ = moe_apply(p, x, cfg)
+        return jnp.sum(y)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(p, x).jaxpr
+    widths = (cfg.d_model, cfg.moe_d_ff)
+    assert _wide_rows(jaxpr, len(RUNGS) - 1, RUNGS[0], widths) == []
+    assert _wide_rows(jaxpr, len(RUNGS) - 1, RUNGS[-1], widths)
+    assert _wide_rows(jaxpr, 0, RUNGS[0], widths)
